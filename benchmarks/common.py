@@ -23,10 +23,12 @@ def time_plan(plan: ir.Plan, catalog: ir.Catalog, repeats: int = 3,
     tables = dict(catalog.tables)
     if cache is not None:
         run_tables = cache.get_or_compile(plan, catalog)
-        run = lambda: run_tables(tables)
     else:
+        # tables are arguments, as in PlanCache: closed over, they would be
+        # baked into the compiled program as constants
         pplan = lower(plan, catalog)
-        run = jax.jit(lambda: ph.run(pplan, tables))
+        run_tables = jax.jit(lambda t: ph.run(pplan, t))
+    run = lambda: run_tables(tables)
 
     t0 = time.perf_counter()
     out = run()

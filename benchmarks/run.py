@@ -26,6 +26,9 @@ def main() -> None:
     args = ap.parse_args()
     q = args.quick
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (ablation, complex_queries, cost_model_bench,
                             kernels_bench, optimizers, plan_cache_bench,
                             random_queries, roofline, serving_bench,

@@ -6,6 +6,7 @@ query in the three-level IR, optimize it with MCTS, execute, verify.
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ir
 from repro.core.executor import execute
 from repro.core.planner import analytic_cost_fn, optimize_vanilla_mcts, timed
@@ -81,4 +82,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

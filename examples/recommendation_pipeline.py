@@ -12,6 +12,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import optimizer as om
 from repro.core.executor import execute
 from repro.core.mcts import ReusableMCTS
@@ -60,4 +61,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core import ir
 from repro.core.executor import execute
@@ -83,4 +84,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import argparse
 import dataclasses
 import shutil
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.train.loop import train
 
@@ -45,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

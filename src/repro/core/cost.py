@@ -70,13 +70,23 @@ class DeviceProfile:
     def detect(cls) -> "DeviceProfile":
         """A fresh profile for the host's JAX backend.
 
+        A TPU's prior is chosen by the ``device_kind`` JAX reports
+        (``TPU_PRIORS``); a TPU kind without a prior raises instead of
+        borrowing another chip's peaks, because every pallas-vs-jnp,
+        compaction and PartSpec decision is costed against them.
         Returns a *copy* (profiles are mutable calibration targets; the
         module singletons below are priors, never calibrated in place).
         """
         import jax
         backend = jax.default_backend()
         if backend == "tpu":
-            prior = TPU_PROFILE
+            kind = jax.devices()[0].device_kind
+            if kind not in TPU_PRIORS:
+                raise ValueError(
+                    f"no cost prior for TPU device kind {kind!r}; known: "
+                    f"{sorted(TPU_PRIORS)} (add its published peaks to "
+                    f"repro.core.cost.TPU_PRIORS)")
+            prior = TPU_PRIORS[kind]
         elif backend in ("gpu", "cuda", "rocm"):
             prior = GPU_PROFILE
         else:
@@ -88,7 +98,15 @@ class DeviceProfile:
 # on real accelerators; the "devices" of a forced CPU host mesh share one
 # address space, so a collective there is a plain memcpy whose *volume*
 # already rides data_bytes — only a tiny per-launch latency remains
-TPU_PROFILE = DeviceProfile(collective_overhead_s=1e-6)
+#
+# TPU v5e peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM bandwidth, 16 GB HBM per chip. vmem_bw and the overheads are
+# guesses, not measurements.
+TPU_PROFILE = DeviceProfile(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                            collective_overhead_s=1e-6)
+
+# TPU priors keyed by ``jax.Device.device_kind`` (what the chip reports)
+TPU_PRIORS = {"TPU v5 lite": TPU_PROFILE}
 
 GPU_PROFILE = DeviceProfile(name="gpu-a100", peak_flops=312e12,
                             hbm_bw=1.55e12, vmem_bw=5.0e12,

@@ -17,7 +17,7 @@ and no operator changes. This module owns the mesh plumbing for that path:
                        fitting policy actually sharded.
 * ``mesh_signature`` — the mesh's contribution to compiled-plan cache keys.
 * ``shard_batch``    — wrap a stacked-batch function in ``shard_map`` over
-                       the mesh's batch axes (jax-version compatible).
+                       the mesh's batch axes.
 
 It is also the *one* home of the intra-query partition arithmetic the
 PartSpec layer uses (``repro.core.physical.PartSpec`` /
@@ -109,23 +109,12 @@ def shard_batch(fn: Callable, mesh: Mesh) -> Callable:
     Callers must have checked ``can_shard`` — the spec here is
     unconditional. Weights and other closed-over arrays are replicated.
     """
-    try:  # jax >= 0.6
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
     spec = P(batch_axes(mesh))
-    # disable replication checking: the plan body is arbitrary jnp code over
+    # replication checking off: the plan body is arbitrary jnp code over
     # closed-over (replicated) weights; the checker rejects some primitives
-    # it cannot type, and we never rely on rep types. The kwarg was renamed
-    # check_rep -> check_vma across jax versions; try both before falling
-    # back to the (checked) default.
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                              **kw)
-        except TypeError:
-            continue
-    raise TypeError("shard_map signature not recognized")
+    # it cannot type, and we never rely on its types
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def shard_replicated(fn: Callable, mesh: Mesh) -> Callable:
@@ -137,18 +126,9 @@ def shard_replicated(fn: Callable, mesh: Mesh) -> Callable:
     single-oversized-query counterpart of ``shard_batch``: there is no
     stacked batch axis to split, the *operators* are partitioned instead.
     """
-    try:  # jax >= 0.6
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
     spec = P()  # replicated in/out; movement is explicit inside the body
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                              **kw)
-        except TypeError:
-            continue
-    raise TypeError("shard_map signature not recognized")
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------

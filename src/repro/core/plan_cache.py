@@ -194,6 +194,14 @@ class PlanCache:
         # per-(signature, backend, epoch) costed-lowering results: warm
         # dispatches pay one LRU lookup, not a candidate enumeration
         self._lowered = LRUCache(256)
+        # times a request was served by something other than what it asked
+        # for: 'sharded' / 'partitioned' -> the single-device executable,
+        # 'budget_pruned_all' -> a lowering whose every candidate busted
+        # the memory budget (so the chosen plan does not fit)
+        self.fallbacks: Dict[str, int] = {}
+
+    def _fell_back(self, what: str) -> None:
+        self.fallbacks[what] = self.fallbacks.get(what, 0) + 1
 
     @property
     def stats(self) -> CacheStats:
@@ -271,6 +279,8 @@ class PlanCache:
         low = costed_lowering.lower_costed(plan, catalog,
                                            profile=self.profile,
                                            backend=backend, ways=ways)
+        if low.budget_pruned_all:
+            self._fell_back("budget_pruned_all")
         self._lowered.put(mk, (weakref.ref(catalog), low))
         return low
 
@@ -311,6 +321,9 @@ class PlanCache:
                 # trace), and unused tables never cross the jit boundary
                 return jfn({k: tables[k] for k in names})
 
+            # the executable's own jax lowering, e.g. for
+            # ``fn.lower(tables).compile().as_text()``
+            fn.lower = lambda tables: jfn.lower({k: tables[k] for k in names})
             self._cache.put(key, fn)
         return fn
 
@@ -401,13 +414,15 @@ class PlanCache:
         device count doesn't divide (``core.mesh.can_shard``, the same
         divisibility-fitting policy as ``models.sharding``) — fall back to
         the plain batched executable under *its* key, so fallback traffic
-        shares the existing entry instead of compiling a duplicate.
+        shares the existing entry instead of compiling a duplicate; each
+        one counts in ``fallbacks['sharded']``.
         """
         from repro.core import mesh as mesh_util
 
         if batch_size < 1:
             raise ValueError(f"batch_size must be >= 1, got {batch_size}")
         if not mesh_util.can_shard(mesh, batch_size):
+            self._fell_back("sharded")
             return self.get_or_compile_batched(plan, catalog, batch_size,
                                                cache_key=cache_key)
         base = self._strip_cl(cache_key if cache_key is not None
@@ -443,11 +458,13 @@ class PlanCache:
         distribution choice, orthogonal to the caller's kernel choice).
         Single-device meshes, and lowerings that decide partitioning does
         not pay (every PartSpec replicated), fall back to the plain
-        executable under *its* key — no duplicate compilation."""
+        executable under *its* key — no duplicate compilation — and count
+        in ``fallbacks['partitioned']``."""
         from repro.core import mesh as mesh_util
 
         ways = mesh_util.batch_ways(mesh) if mesh is not None else 1
         if ways <= 1:
+            self._fell_back("partitioned")
             return self.get_or_compile(plan, catalog, backend=backend,
                                        cache_key=cache_key)
         base = self._strip_cl(cache_key if cache_key is not None
@@ -460,6 +477,7 @@ class PlanCache:
             # the oracle kept every node replicated: the partitioned
             # program would be the plain one run redundantly on every
             # device — share the plain executable instead
+            self._fell_back("partitioned")
             return self.get_or_compile(plan, catalog, backend=backend)
         key = base + "#cl=" + low.signature
         fn = self._cache.get(key)

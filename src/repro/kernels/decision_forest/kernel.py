@@ -2,8 +2,9 @@
 
 TPU adaptation: tree traversal is branch- and gather-free. For a block of
 rows and one tree:
-  1. feature gather  x[feat[j]]  →  xv = x @ onehot(feat)ᵀ  (MXU matmul with
-     a precomputed one-hot matrix, done once per tree, host-side in ops.py)
+  1. feature gather  x[feat[j]]  →  xv = x @ onehot(feat)ᵀ  (MXU matmul at
+     HIGHEST precision, so exact, with a precomputed one-hot matrix, done
+     once per tree, host-side in ops.py)
   2. decision bits   D = xv > thresh                (VPU compare, all nodes)
   3. traversal       node ← 2·node+1+D[node]; the D[node] gather is a
      one-hot select: sum((node == iota) · D)        (VPU, no gather op)
@@ -36,7 +37,10 @@ def _forest_kernel(x_ref, fonehot_ref, thresh_ref, leaf_ref, o_ref, acc_ref,
     th = thresh_ref[0]                    # [1, nodes] -> broadcast
     lv = leaf_ref[0]                      # [1, leaves]
     n_nodes = fo.shape[1]
-    xv = jnp.dot(x, fo, preferred_element_type=jnp.float32)  # [bm, nodes]
+    # the one-hot matmul is a gather and must be exact: at default precision
+    # the MXU rounds x to bf16 and flips decisions near the thresholds
+    xv = jnp.dot(x, fo, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)  # [bm, nodes]
     dec = (xv > th).astype(jnp.float32)   # [bm, nodes]
     bm = x.shape[0]
     node = jnp.zeros((bm,), jnp.int32)

@@ -80,8 +80,13 @@ def canonical(t: NpTable) -> NpTable:
     n = len(next(iter(t.values())))
     if n == 0:
         return t
+    # exact (integer/bool) columns lead the order: two results whose floats
+    # differ within tolerance could round to different keys and pair up the
+    # wrong rows if a float column came first
+    names = sorted(t, key=lambda k: (np.issubdtype(t[k].dtype, np.floating),
+                                     k))
     keys = []
-    for name in sorted(t):
+    for name in names:
         arr = t[name]
         if arr.ndim == 1:
             keys.append(np.round(arr.astype(np.float64), 4))

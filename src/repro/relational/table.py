@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.relational.oracle import canonical
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -90,18 +92,4 @@ class Table:
     def canonical(self) -> Dict[str, np.ndarray]:
         """Valid rows sorted by a total order over all scalar columns — used
         to compare plan outputs irrespective of row order."""
-        data = self.to_numpy()
-        if not data:
-            return data
-        n = next(iter(data.values())).shape[0]
-        if n == 0:
-            return data
-        keys = []
-        for name in sorted(data):
-            arr = data[name]
-            if arr.ndim == 1:
-                keys.append(np.round(arr.astype(np.float64), 4))
-            else:
-                keys.append(np.round(arr.astype(np.float64).sum(axis=tuple(range(1, arr.ndim))), 4))
-        order = np.lexsort(tuple(reversed(keys)))
-        return {k: v[order] for k, v in data.items()}
+        return canonical(self.to_numpy())

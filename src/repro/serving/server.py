@@ -230,4 +230,7 @@ class QueryServer:
                                if total_disp else 0.0),
             "cache": self.cache.stats.as_dict(),
             "traces": self.cache.traces,
+            # requests served by a single-device executable they did not
+            # ask for, and over-budget lowerings (see PlanCache.fallbacks)
+            "fallbacks": dict(self.cache.fallbacks),
         }

@@ -17,20 +17,46 @@ from repro.mlfuncs.registry import Registry
 # DeviceProfile.detect
 # ---------------------------------------------------------------------------
 
-def test_detect_maps_jax_backend(monkeypatch):
+class _FakeDevice:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def _fake_backend(monkeypatch, backend, kind):
     import jax
-    for backend, name, pallas in (("tpu", "tpu-v5e", True),
-                                  ("gpu", "gpu-a100", False),
-                                  ("cpu", "cpu", False)):
-        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_FakeDevice(kind)])
+
+
+def test_detect_maps_jax_backend(monkeypatch):
+    for backend, kind, name, pallas in (
+            ("tpu", "TPU v5 lite", "tpu-v5e", True),
+            ("gpu", "NVIDIA A100-SXM4-40GB", "gpu-a100", False),
+            ("cpu", "cpu", "cpu", False)):
+        _fake_backend(monkeypatch, backend, kind)
         p = cost.DeviceProfile.detect()
         assert p.name == name and p.supports_pallas == pallas
+    monkeypatch.undo()
     # detect() returns fresh copies: calibrating one must not leak into the
     # module priors
     p = cost.DeviceProfile.detect()
     p.op_overhead_s = 123.0
     assert cost.CPU_PROFILE.op_overhead_s != 123.0
     assert cost.DeviceProfile.detect().op_overhead_s != 123.0
+
+
+def test_detect_v5e_kind_gets_v5e_published_peaks(monkeypatch):
+    _fake_backend(monkeypatch, "tpu", "TPU v5 lite")
+    p = cost.DeviceProfile.detect()
+    assert (p.peak_flops, p.hbm_bw) == (197e12, 819e9)
+    assert p is not cost.TPU_PRIORS["TPU v5 lite"]  # a copy, not the prior
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v5", "TPU v6 lite", ""])
+def test_detect_unknown_tpu_kind_raises(monkeypatch, kind):
+    _fake_backend(monkeypatch, "tpu", kind)
+    with pytest.raises(ValueError, match="no cost prior for TPU device kind"):
+        cost.DeviceProfile.detect()
 
 
 def test_profile_signature_tracks_calibratable_fields():

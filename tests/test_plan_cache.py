@@ -123,6 +123,29 @@ def test_compile_plan_goes_through_cache():
     np.testing.assert_allclose(a["score"], b["score"])
 
 
+def test_fallbacks_are_counted_and_served_stats_report_them():
+    """A request served by something other than what it asked for counts in
+    ``PlanCache.fallbacks``: on a one-device mesh the sharded and the
+    partitioned entry points serve the plain executables, and a memory
+    budget nothing fits prunes every lowering candidate."""
+    from repro.core.mesh import data_mesh
+    from repro.serving import QueryServer
+
+    plan, cat = _mini_setup()
+    one = data_mesh(1)
+    cache = PlanCache()
+    cache.get_or_compile_sharded(plan, cat, 2, one)
+    cache.get_or_compile_partitioned(plan, cat, one)
+    assert cache.fallbacks == {"sharded": 1, "partitioned": 1}
+
+    server = QueryServer(max_batch_size=1, memory_budget=64.0)
+    req = server.submit(plan, cat, dict(cat.tables))
+    server.drain()
+    assert req.error is None
+    assert server.stats()["fallbacks"] == {"budget_pruned_all": 1}
+    assert QueryServer().stats()["fallbacks"] == {}
+
+
 def test_lru_cache_bounds_and_stats():
     c = LRUCache(maxsize=2)
     c.put("a", 1)

@@ -162,3 +162,16 @@ def test_prop_cross_join_cardinality(n, m, seed):
     x = ops.cross_join(a, b)
     assert x.capacity == n * m
     assert int(x.num_valid()) == n * m
+
+
+def test_canonical_orders_by_exact_columns_first():
+    # "a_score" sorts before "id" by name; values within tolerance that round
+    # to different 4-decimal keys must not pair up the wrong rows
+    a = Table.from_columns({"a_score": jnp.asarray([0.12345, 0.12344]),
+                            "id": jnp.asarray([7, 3], jnp.int32)})
+    b = Table.from_columns({"a_score": jnp.asarray([0.12344, 0.12346]),
+                            "id": jnp.asarray([7, 3], jnp.int32)})
+    ca, cb = a.canonical(), b.canonical()
+    np.testing.assert_array_equal(ca["id"], [3, 7])
+    np.testing.assert_array_equal(ca["id"], cb["id"])
+    np.testing.assert_allclose(ca["a_score"], cb["a_score"], atol=5e-5)

@@ -123,12 +123,8 @@ def test_compressed_psum_shard_map():
         return compress.compressed_psum(grads, err, "data")
 
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    out, err2 = shard_map(f, mesh=mesh,
-                          in_specs=(P(), P()), out_specs=(P(), P()))(g, err)
+    out, err2 = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=(P(), P()))(g, err)
     np.testing.assert_allclose(np.asarray(out["w"]), 0.5, rtol=1e-2)
 
 
