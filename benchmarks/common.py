@@ -57,18 +57,5 @@ def best_time(fn: Callable, repeats: int = 9) -> float:
     return min(ts)
 
 
-def time_fn(fn: Callable, *args, repeats: int = 5) -> float:
-    out = fn(*args)
-    jax.block_until_ready(out)
-    times = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2]
-
-
 def csv_line(name: str, us_per_call: float, derived: str = "") -> str:
     return f"{name},{us_per_call:.1f},{derived}"

@@ -30,12 +30,11 @@ def main() -> None:
     enable_compile_cache()
 
     from benchmarks import (ablation, complex_queries, cost_model_bench,
-                            kernels_bench, optimizers, plan_cache_bench,
-                            random_queries, roofline, serving_bench,
-                            sharded_bench, simplified_analytics)
+                            optimizers, plan_cache_bench, random_queries,
+                            serving_bench, sharded_bench,
+                            simplified_analytics)
 
     suites = {
-        "kernels": lambda: kernels_bench.run(),
         "plan_cache": lambda: plan_cache_bench.run(scale=0.3 if q else 0.5),
         # cost-oracle accuracy: predicted vs measured + calibration error;
         # the JSON summary gains a `cost_model` section from this suite
@@ -61,7 +60,6 @@ def main() -> None:
             iterations=6 if q else 15, train_steps=30 if q else 80),
         "random_queries": lambda: random_queries.run(
             n_queries=8 if q else 24, iterations=5 if q else 10),
-        "roofline": lambda: roofline.run(),
     }
     only = set(args.only.split(",")) if args.only else None
     if only:

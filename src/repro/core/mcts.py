@@ -15,6 +15,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core import cost, ir
 from repro.core.rules import ALL_RULES
 from repro.core.rules.base import RuleConfig
@@ -24,6 +25,15 @@ ACTION_SPACE = ["R1-1", "R1-2", "R1-3", "R1-4-merge", "R1-4-split", "compact",
                 "R4-1-fuse", "R4-1-unfuse", "R4-2", "R4-4"]
 
 CostFn = Callable[[ir.Plan], float]
+
+
+def spanned_cost(cost_fn: CostFn) -> CostFn:
+    """``cost_fn`` with each call in an ``optimizer.cost`` span, whose
+    durations also go to the monitoring bus (``tracing.PUBLISHED``)."""
+    def cost_call(plan: ir.Plan) -> float:
+        with tracing.span("optimizer.cost"):
+            return cost_fn(plan)
+    return cost_call
 
 
 def _heuristic_narrow(action: str, plan: ir.Plan, cfgs: List[RuleConfig],
@@ -46,6 +56,13 @@ def configure_action(plan: ir.Plan, catalog: ir.Catalog, action: str,
                      ) -> Optional[Tuple[ir.Plan, RuleConfig]]:
     """Pick the best configuration of `action` for this plan (or None if the
     rule is inapplicable)."""
+    with tracing.span("optimizer.rewrite"):
+        return _configure(plan, catalog, action, cost_fn, topk)
+
+
+def _configure(plan: ir.Plan, catalog: ir.Catalog, action: str,
+               cost_fn: CostFn, topk: int
+               ) -> Optional[Tuple[ir.Plan, RuleConfig]]:
     rule = ALL_RULES[action]
     cfgs = rule.configs(plan, catalog)
     if not cfgs:
@@ -105,7 +122,8 @@ class VanillaMCTS:
         self.catalog = catalog
         # default reward oracle: the shared plan_cost entry point (the same
         # oracle costed lowering scores its physical candidates with)
-        self.cost_fn = cost_fn or (lambda p: cost.plan_cost(p, catalog))
+        self.cost_fn = spanned_cost(
+            cost_fn or (lambda p: cost.plan_cost(p, catalog)))
         self.iterations = iterations
         self.c = c
         self.max_depth = max_depth
@@ -148,6 +166,10 @@ class VanillaMCTS:
         return cur
 
     def optimize(self, plan: ir.Plan) -> Tuple[ir.Plan, Dict]:
+        with tracing.span("optimizer.search"):
+            return self._search(plan)
+
+    def _search(self, plan: ir.Plan) -> Tuple[ir.Plan, Dict]:
         root = _VNode(plan=plan, cost=self.cost_fn(plan))
         best_plan, best_cost = plan, root.cost
         for _ in range(self.iterations):
@@ -279,7 +301,11 @@ class ReusableMCTS:
 
     # -- search (Alg. 5) ----------------------------------------------------
     def optimize(self, plan: ir.Plan, catalog: ir.Catalog) -> Tuple[ir.Plan, Dict]:
-        cost_fn = self.cost_fn_factory(catalog)
+        with tracing.span("optimizer.search"):
+            return self._search(plan, catalog)
+
+    def _search(self, plan: ir.Plan, catalog: ir.Catalog) -> Tuple[ir.Plan, Dict]:
+        cost_fn = spanned_cost(self.cost_fn_factory(catalog))
         emb0 = self.embed_fn(plan, catalog)
         root, hit = self._get_or_create(emb0)
         self.queries += 1

@@ -451,20 +451,27 @@ def run_repartition(node: PRepartition, t: Table, axis: Optional[str]) -> Table:
 
 def _run_stage(stage: Stage, t: Table, registry: Registry) -> Table:
     if isinstance(stage, FilterStage):
-        mask = jnp.asarray(eval_expr(stage.pred, t, registry)).astype(bool)
-        mask = as_column(mask, t.capacity)
-        return ops.filter_(t, mask)
+        with jax.named_scope("filter"):
+            mask = jnp.asarray(eval_expr(stage.pred, t, registry)).astype(bool)
+            mask = as_column(mask, t.capacity)
+            return ops.filter_(t, mask)
     if isinstance(stage, ProjectStage):
-        new_cols = {name: as_column(eval_expr(e, t, registry), t.capacity)
-                    for name, e in stage.outputs}
-        return ops.project(t, new_cols, keep=stage.keep)
+        with jax.named_scope("project"):
+            new_cols = {name: as_column(eval_expr(e, t, registry), t.capacity)
+                        for name, e in stage.outputs}
+            return ops.project(t, new_cols, keep=stage.keep)
     if isinstance(stage, CompactStage):
-        return ops.compact(t, stage.capacity)
+        with jax.named_scope("compact"):
+            return ops.compact(t, stage.capacity)
     raise TypeError(type(stage))
 
 
 def run_node(node: PhysNode, tables: Dict[str, Table],
              registry: Registry, axis: Optional[str] = None) -> Table:
+    """Run ``node``'s subtree. Each operator's own work runs inside
+    ``jax.named_scope(<operator>)`` (``tracing.OPERATORS``) and its children
+    outside it, so that a compiled op's innermost scope names the operator
+    that did the work."""
     if isinstance(node, PScan):
         return tables[node.table]
     if isinstance(node, PPipeline):
@@ -475,33 +482,42 @@ def run_node(node: PhysNode, tables: Dict[str, Table],
     if isinstance(node, PJoin):
         lt = run_node(node.left, tables, registry, axis)
         rt = run_node(node.right, tables, registry, axis)
-        return ops.fk_join(lt, rt, node.left_key, node.right_key, node.rprefix)
+        with jax.named_scope("join"):
+            return ops.fk_join(lt, rt, node.left_key, node.right_key,
+                               node.rprefix)
     if isinstance(node, PCrossJoin):
         lt = run_node(node.left, tables, registry, axis)
         rt = run_node(node.right, tables, registry, axis)
-        return ops.cross_join(lt, rt, node.aprefix, node.bprefix)
+        with jax.named_scope("cross_join"):
+            return ops.cross_join(lt, rt, node.aprefix, node.bprefix)
     if isinstance(node, PAggregate):
         t = run_node(node.child, tables, registry, axis)
-        return ops.aggregate(t, node.key, dict(node.aggs), node.num_groups)
+        with jax.named_scope("aggregate"):
+            return ops.aggregate(t, node.key, dict(node.aggs),
+                                 node.num_groups)
     if isinstance(node, PBlockedMatmul):
         t = run_node(node.child, tables, registry, axis)
-        w = matmul_weight(registry, node.fn)
-        if node.mode == "relational":
-            y = blocked_matmul_relational(t, node.x_col, w, node.n_tiles)
-        else:
-            y = blocked_matmul_fused(t[node.x_col], w, node.n_tiles, node.backend)
-        return ops.project(t, {node.out_col: y}, keep=node.keep)
+        with jax.named_scope("blocked_matmul"):
+            w = matmul_weight(registry, node.fn)
+            if node.mode == "relational":
+                y = blocked_matmul_relational(t, node.x_col, w, node.n_tiles)
+            else:
+                y = blocked_matmul_fused(t[node.x_col], w, node.n_tiles,
+                                         node.backend)
+            return ops.project(t, {node.out_col: y}, keep=node.keep)
     if isinstance(node, PForestRelational):
         t = run_node(node.child, tables, registry, axis)
-        fn = registry.get(node.fn)
-        if node.mode == "relational":
-            y = forest_relational(t, node.x_col, fn)
-        else:
-            y = forest_fused(t[node.x_col], fn, node.backend)
-        return ops.project(t, {node.out_col: y}, keep=node.keep)
+        with jax.named_scope("forest"):
+            fn = registry.get(node.fn)
+            if node.mode == "relational":
+                y = forest_relational(t, node.x_col, fn)
+            else:
+                y = forest_fused(t[node.x_col], fn, node.backend)
+            return ops.project(t, {node.out_col: y}, keep=node.keep)
     if isinstance(node, PRepartition):
         t = run_node(node.child, tables, registry, axis)
-        return run_repartition(node, t, axis)
+        with jax.named_scope("repartition"):
+            return run_repartition(node, t, axis)
     raise TypeError(type(node))
 
 

@@ -54,6 +54,7 @@ from typing import Any, Callable, Dict, Hashable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core import costed_lowering, ir
 from repro.core import physical as ph
 from repro.core.cost import DeviceProfile
@@ -313,7 +314,7 @@ class PlanCache:
                 self.traces += 1  # python side effect: runs only while tracing
                 return ph.run(pplan, tables)
 
-            jfn = jax.jit(traced)
+            jfn = _jit_named(traced, "plan_single", key, 1)
 
             def fn(tables: Dict[str, Table]) -> Table:
                 # normalize to the scanned tables only: full-catalog and
@@ -380,11 +381,15 @@ class PlanCache:
 
             def traced(tables_seq):
                 self.traces += 1  # python side effect: runs only while tracing
-                out = body(stack_tables(list(tables_seq)))
-                return tuple(unstack_table(out, i)
-                             for i in range(batch_size))
+                with jax.named_scope("scan"):
+                    stacked = stack_tables(list(tables_seq))
+                out = body(stacked)
+                with jax.named_scope("scan"):
+                    return tuple(unstack_table(out, i)
+                                 for i in range(batch_size))
 
-            jfn = jax.jit(traced)
+            jfn = _jit_named(traced, f"plan_{kind}{batch_size}", key,
+                             batch_size)
 
             def fn(tables_seq):
                 if len(tables_seq) != batch_size:
@@ -489,7 +494,8 @@ class PlanCache:
                 self.traces += 1  # python side effect: runs only while tracing
                 return ph.run(pplan, tables, axis=mesh_util.DATA_AXIS)
 
-            jfn = jax.jit(mesh_util.shard_replicated(traced, mesh))
+            jfn = _jit_named(mesh_util.shard_replicated(traced, mesh),
+                             "plan_partitioned", key, 1)
 
             def fn(tables: Dict[str, Table]) -> Table:
                 return jfn({k: tables[k] for k in names})
@@ -500,6 +506,32 @@ class PlanCache:
     def __call__(self, plan: ir.Plan, catalog: ir.Catalog) -> Table:
         """Convenience: compile-or-reuse, then execute on catalog tables."""
         return self.get_or_compile(plan, catalog)(dict(catalog.tables))
+
+
+def _jit_named(fun: Callable, name: str, key: str, batch_size: int):
+    """``jax.jit(fun)`` compiled as module ``jit_<name>``, so that a trace's
+    modules tell the single, batched, sharded and partitioned executables
+    apart (the name is also part of the persistent compile cache's key).
+    The first call records the executable's operator scopes
+    (``tracing.record_executable``); the lowering and the compiled
+    executable are then JAX's in-memory cache hits."""
+    def named(*args):
+        return fun(*args)
+
+    named.__name__ = named.__qualname__ = name
+    jfn = jax.jit(named)
+    recorded = []
+
+    def call(*args):
+        out = jfn(*args)
+        if not recorded:
+            recorded.append(key)
+            tracing.record_executable(key, batch_size,
+                                      jfn.lower(*args).compile())
+        return out
+
+    call.lower = jfn.lower
+    return call
 
 
 def stack_tables(tables_list) -> Dict[str, Table]:

@@ -48,7 +48,6 @@ class MicroBatcher:
         self.max_wait_s = max_wait_s
         self._groups: "OrderedDict[str, _Group]" = OrderedDict()
         self.groups_formed = 0          # micro-batches dispatched so far
-        self.requests_admitted = 0
 
     # -- admission ---------------------------------------------------------
     def add(self, req: QueryRequest) -> None:
@@ -56,7 +55,6 @@ class MicroBatcher:
         if group is None:
             group = self._groups[req.key] = _Group(requests=deque())
         group.requests.append(req)
-        self.requests_admitted += 1
 
     def pending(self) -> int:
         return sum(len(g.requests) for g in self._groups.values())

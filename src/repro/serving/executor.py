@@ -34,6 +34,7 @@ from typing import Callable, Optional
 
 import jax
 
+from repro import tracing
 from repro.core import costed_lowering
 from repro.core import mesh as mesh_util
 from repro.core.plan_cache import LRUCache, PlanCache
@@ -49,8 +50,6 @@ class BatchedExecutor:
         self.backend = backend  # node-level lowering override (jnp/pallas)
         self.mesh = mesh        # multi-device batch sharding, when eligible
         self.clock = clock  # same timebase as request timestamps
-        self.dispatches = 0
-        self.batched_dispatches = 0
         self.sharded_dispatches = 0
         self.partitioned_dispatches = 0
         # vmapped-vs-sharded is a costed decision (the shared oracle against
@@ -90,26 +89,19 @@ class BatchedExecutor:
         sharded = (not partitioned) and self._use_sharded(batch)
         batch.sharded, batch.partitioned = sharded, partitioned
         t0 = self.clock()
-        if partitioned:
-            # the caller's node-level kernel override constrains the
-            # partitioned lowering too — partitioning is a distribution
-            # choice, not a kernel one, so the two compose
-            run = self.cache.get_or_compile_partitioned(
-                rep.plan, rep.catalog, self.mesh, backend=self.backend,
-                cache_key=batch.key)
-            results = [run(r.tables) for r in reqs]
-            jax.block_until_ready(results)
-            # per completed *batch*, like every other dispatch counter
-            self.partitioned_dispatches += 1
-        elif len(reqs) == 1:
-            run = self.cache.get_or_compile(rep.plan, rep.catalog,
-                                            backend=self.backend,
-                                            cache_key=batch.key)
-            out = run(rep.tables)
-            jax.block_until_ready(out)
-            results = [out]
-        else:
-            if sharded:
+        with tracing.span("executor.lookup"):
+            if partitioned:
+                # the caller's node-level kernel override constrains the
+                # partitioned lowering too — partitioning is a distribution
+                # choice, not a kernel one, so the two compose
+                run = self.cache.get_or_compile_partitioned(
+                    rep.plan, rep.catalog, self.mesh, backend=self.backend,
+                    cache_key=batch.key)
+            elif len(reqs) == 1:
+                run = self.cache.get_or_compile(rep.plan, rep.catalog,
+                                                backend=self.backend,
+                                                cache_key=batch.key)
+            elif sharded:
                 run = self.cache.get_or_compile_sharded(
                     rep.plan, rep.catalog, len(reqs), self.mesh,
                     cache_key=batch.key)
@@ -117,15 +109,24 @@ class BatchedExecutor:
                 run = self.cache.get_or_compile_batched(
                     rep.plan, rep.catalog, len(reqs), backend=self.backend,
                     cache_key=batch.key)
-            results = run(tuple(r.tables for r in reqs))
+        # host enqueue (on a cache miss also tracing and compiling), then
+        # the host waiting for the device
+        with tracing.span("executor.launch"):
+            if partitioned:
+                results = [run(r.tables) for r in reqs]
+            elif len(reqs) == 1:
+                results = [run(rep.tables)]
+            else:
+                results = run(tuple(r.tables for r in reqs))
+        with tracing.span("executor.wait"):
             jax.block_until_ready(results)
-            # counters record *completed* dispatches only — a raising
-            # dispatch is the server's failure path, not a sharded/batched one
-            self.batched_dispatches += 1
-            if sharded:
-                self.sharded_dispatches += 1
+        # counters record *completed* dispatches only (per batch) — a
+        # raising dispatch is the server's failure path
+        if partitioned:
+            self.partitioned_dispatches += 1
+        elif sharded:
+            self.sharded_dispatches += 1
         dt = self.clock() - t0
-        self.dispatches += 1
         for req, res in zip(reqs, results):
             req.result = res
             req.done = True

@@ -18,6 +18,7 @@ import time
 import weakref
 from typing import Callable, Dict, Optional
 
+from repro import tracing
 from repro.core import ir
 from repro.core.plan_cache import LRUCache, PlanCache, scan_table_names
 from repro.relational.table import Table
@@ -119,6 +120,11 @@ class QueryServer:
                tables: Optional[Dict[str, Table]] = None) -> QueryRequest:
         """Admit one in-flight query; returns immediately with a pending
         request whose ``result`` is filled by a later ``step``/``drain``."""
+        with tracing.span("serving.submit", rid=self._next_rid):
+            return self._admit(plan, catalog, tables)
+
+    def _admit(self, plan: ir.Plan, catalog: ir.Catalog,
+               tables: Optional[Dict[str, Table]]) -> QueryRequest:
         if tables is None:
             tables = dict(catalog.tables)
         memo = self._submit_memo.get((id(plan), id(catalog)))
@@ -178,37 +184,43 @@ class QueryServer:
     def _dispatch(self, batches) -> int:
         done = 0
         for batch in batches:
-            sig = self.signatures[batch.key]
-            try:
-                dt = self.executor.dispatch(batch)
-            except Exception as e:  # noqa: BLE001 — a bad payload (e.g.
-                # tables whose shapes disagree with the signature's schema)
-                # must fail its own batch, not hang its requests forever or
-                # take the serving loop down with them
-                now = self.clock()
-                for req in batch.requests:
-                    req.done = True
-                    req.error = f"{type(e).__name__}: {e}"
-                    req.dispatch_t = req.finish_t = now
-                sig.failures += len(batch)
-                self.failed += len(batch)
-                continue
-            sig.dispatches += 1
-            sig.served_requests += len(batch)
-            sig.total_dispatch_s += dt
-            if batch.sharded:
-                sig.sharded_dispatches += 1
-                sig.ways = self._ways
-            if batch.partitioned:
-                sig.partitioned_dispatches += 1
-                sig.ways = self._ways
-            for req in batch.requests:
-                sig.total_wait_s += req.queue_wait_s
-                if req.batch_size >= 2:
-                    sig.batched_requests += 1
-            done += len(batch)
+            with tracing.span("serving.dispatch",
+                              rid0=batch.requests[0].rid, size=len(batch)):
+                done += self._dispatch_one(batch)
         self.completed += done
         return done
+
+    def _dispatch_one(self, batch) -> int:
+        """Run one micro-batch; returns the requests it completed."""
+        sig = self.signatures[batch.key]
+        try:
+            dt = self.executor.dispatch(batch)
+        except Exception as e:  # noqa: BLE001 — a bad payload (e.g.
+            # tables whose shapes disagree with the signature's schema)
+            # must fail its own batch, not hang its requests forever or
+            # take the serving loop down with them
+            now = self.clock()
+            for req in batch.requests:
+                req.done = True
+                req.error = f"{type(e).__name__}: {e}"
+                req.dispatch_t = req.finish_t = now
+            sig.failures += len(batch)
+            self.failed += len(batch)
+            return 0
+        sig.dispatches += 1
+        sig.served_requests += len(batch)
+        sig.total_dispatch_s += dt
+        if batch.sharded:
+            sig.sharded_dispatches += 1
+            sig.ways = self._ways
+        if batch.partitioned:
+            sig.partitioned_dispatches += 1
+            sig.ways = self._ways
+        for req in batch.requests:
+            sig.total_wait_s += req.queue_wait_s
+            if req.batch_size >= 2:
+                sig.batched_requests += 1
+        return len(batch)
 
     # -- introspection -----------------------------------------------------
     def pending(self) -> int:
