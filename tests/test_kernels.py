@@ -43,8 +43,18 @@ def test_block_matmul(m, k, n, t):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("n,d,t,depth", [(20, 8, 4, 3), (150, 16, 10, 5),
-                                         (64, 29, 25, 6)])
+# each case moves one shape edge of the kernel's schedule: depth (1 and 2
+# nodes a level, levels walked group by group at depth 9), trees (1, a
+# prime, several tree blocks, padding trees), rows (one row, lane padding,
+# several row blocks), features (1 and wide)
+@pytest.mark.parametrize("n,d,t,depth", [
+    (20, 8, 4, 3), (150, 16, 10, 5), (64, 29, 25, 6),
+    (50, 8, 4, 1), (50, 8, 4, 2), (300, 8, 5, 6), (1000, 29, 100, 9),
+    (200, 8, 1, 5), (200, 8, 7, 5), (200, 8, 100, 5), (200, 32, 160, 6),
+    (200, 8, 43, 9),
+    (1, 8, 4, 4), (127, 8, 4, 4), (129, 8, 4, 4), (1000, 8, 4, 4),
+    (2000, 8, 3, 4), (9000, 3, 5, 4),
+    (300, 1, 5, 5), (300, 29, 5, 5), (300, 40, 5, 5)])
 def test_decision_forest(n, d, t, depth):
     from repro.kernels.decision_forest import ops, ref
     x = _arr((n, d))
@@ -55,6 +65,47 @@ def test_decision_forest(n, d, t, depth):
     np.testing.assert_allclose(ops.forest_predict(x, feat, th, leaf),
                                ref.forest_predict(x, feat, th, leaf),
                                rtol=1e-4, atol=1e-4)
+
+
+def _forest_with_ties(n, d, t, depth, seed):
+    """Distinct leaves, and thresholds that equal some rows' feature values
+    exactly: a misrouted row, or a tie sent right, changes the vote."""
+    r = np.random.default_rng(seed)
+    x = r.standard_normal((n, d)).astype(np.float32)
+    nn = 2 ** depth - 1
+    feat = r.integers(0, d, (t, nn)).astype(np.int32)
+    th = r.standard_normal((t, nn)).astype(np.float32)
+    tie = r.random((t, nn)) < 0.5
+    th[tie] = x[r.integers(0, n, tie.sum()), feat[tie]]
+    leaf = np.arange(t * 2 ** depth, dtype=np.float32).reshape(t, -1)
+    return tuple(jnp.asarray(a) for a in (x, feat, th, leaf))
+
+
+def test_decision_forest_routes_every_row_exactly():
+    from repro.kernels.decision_forest import ops, ref
+    x, feat, th, leaf = _forest_with_ties(600, 7, 9, 8, seed=11)
+    np.testing.assert_array_equal(ops.forest_predict(x, feat, th, leaf),
+                                  ref.forest_predict(x, feat, th, leaf))
+
+
+def test_forest_tables_reproduce_the_trees():
+    """The SMEM tables ops.py builds (breadth-first nodes at a per-tree
+    stride, zero-leaf padding trees) are the same forest: the oracle run on
+    them, scaled back to the true tree count, gives the original vote."""
+    from repro.kernels.decision_forest import ops, ref
+    n, d, t, depth = 300, 8, 43, 9
+    x, feat, th, leaf = _forest_with_ties(n, d, t, depth, seed=12)
+    s = ops.schedule(n, d, t, depth)
+    assert t % s["tb"], "the case must need padding trees"
+    f_t, th_t, leaf_t = ops.tables(feat, th, leaf, s["width"], s["tb"])
+    padded = f_t.shape[0] // s["width"]
+    assert padded % s["tb"] == 0 and padded > t
+    back = lambda a, k: a.reshape(padded, s["width"])[:, :k]
+    nn = 2 ** depth - 1
+    vote = ref.forest_predict(x, back(f_t, nn), back(th_t, nn),
+                              back(leaf_t, nn + 1)) * padded / t
+    np.testing.assert_allclose(vote, ref.forest_predict(x, feat, th, leaf),
+                               rtol=1e-6)
 
 
 def test_forest_matches_mlfuncs_atom():

@@ -79,15 +79,24 @@ def _assert_compiled_kernel(jitted, *args):
     assert used < HBM_BYTES, f"{used / 1e9:.2f} GB does not fit one chip"
 
 
-def test_decision_forest_kernel_at_creditcard_width(one_chip,
-                                                    compiled_for_tpu):
-    n_trees, nodes = 100, 2 ** 9 - 1
+def _assert_forest_kernel_compiles(one_chip, rows, d, n_trees, depth):
+    nodes = 2 ** depth - 1
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     _assert_compiled_kernel(
         jax.jit(df_ops.forest_predict),
-        sds((289_000, 29), jnp.float32), sds((n_trees, nodes), jnp.int32),
+        sds((rows, d), jnp.float32), sds((n_trees, nodes), jnp.int32),
         sds((n_trees, nodes), jnp.float32),
         sds((n_trees, nodes + 1), jnp.float32))
+
+
+def test_decision_forest_kernel_at_creditcard_width(one_chip,
+                                                    compiled_for_tpu):
+    _assert_forest_kernel_compiles(one_chip, 289_000, 29, 100, 9)
+
+
+def test_decision_forest_kernel_at_xgboost_fraud_width(one_chip,
+                                                       compiled_for_tpu):
+    _assert_forest_kernel_compiles(one_chip, 289_000, 32, 160, 6)
 
 
 @pytest.mark.parametrize("kernel", ["block_matmul", "fused_dense"])
