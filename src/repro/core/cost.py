@@ -25,6 +25,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core import ir
 from repro.mlfuncs.registry import Registry
 
@@ -49,8 +50,10 @@ class DeviceProfile:
     collective_overhead_s: float = 1e-6
     # per-device working-set budget in bytes (None = unlimited): costed
     # lowering hard-rejects candidates whose phys_peak_memory exceeds it,
-    # and plan_cost applies its paging penalty. The serving tier installs
-    # its real budget here (QueryServer(memory_budget=...)).
+    # and plan_cost prices a plan over it as one that does not run
+    # (``OVER_BUDGET``). ``detect`` sets a TPU's to the device's allocator
+    # limit; the serving tier may install another
+    # (QueryServer(memory_budget=...)).
     memory_budget: Optional[float] = None
     # whether the pallas kernel realizations are executable on this device
     supports_pallas: bool = True
@@ -73,7 +76,9 @@ class DeviceProfile:
         A TPU's prior is chosen by the ``device_kind`` JAX reports
         (``TPU_PRIORS``); a TPU kind without a prior raises instead of
         borrowing another chip's peaks, because every pallas-vs-jnp,
-        compaction and PartSpec decision is costed against them.
+        compaction and PartSpec decision is costed against them. A TPU's
+        memory budget is the device's ``bytes_limit``, so that a plan whose
+        analytic peak does not fit loses to one that does.
         Returns a *copy* (profiles are mutable calibration targets; the
         module singletons below are priors, never calibrated in place).
         """
@@ -87,6 +92,11 @@ class DeviceProfile:
                     f"{sorted(TPU_PRIORS)} (add its published peaks to "
                     f"repro.core.cost.TPU_PRIORS)")
             prior = TPU_PRIORS[kind]
+            # the budget is the device's own: what its allocator may hand out
+            stats = getattr(jax.devices()[0], "memory_stats", lambda: None)()
+            limit = (stats or {}).get("bytes_limit")
+            if limit:
+                return dataclasses.replace(prior, memory_budget=float(limit))
         elif backend in ("gpu", "cuda", "rocm"):
             prior = GPU_PROFILE
         else:
@@ -472,10 +482,52 @@ def phys_op_costs(pplan, catalog: ir.Catalog,
     return out
 
 
+def _dense_bytes(exprs, registry: Registry, cap,
+                 profile: DeviceProfile) -> float:
+    """Working set of the dense layers that ``exprs``' calls run over
+    ``cap`` rows: the widest layer's input and output. A layer of width 1
+    holds neither: XLA reduces it into the ops that produce its input."""
+    widest = 0
+    for e in exprs:
+        for c in _calls(e):
+            g = registry.get(c.fn).graph
+            for n in (g.nodes if g is not None else ()):
+                if n.atom.kind in ("matmul", "fused_dense"):
+                    w = n.atom.params["w"]
+                    if w.shape[1] > 1:
+                        widest = max(widest, w.shape[0] + w.shape[1])
+    return float(widest) * cap * profile.elem_bytes
+
+
+def _stage_exprs(stage) -> tuple:
+    """The expressions a Filter or Project (stage or node) evaluates."""
+    if hasattr(stage, "pred"):
+        return (stage.pred,)
+    return tuple(e for _, e in getattr(stage, "outputs", ()))
+
+
+def _view_bytes(schema, cap, view, profile) -> float:
+    """Bytes of a relation whose ``view`` columns (a cross join's, not
+    materialized) live in its sides' ``view`` bytes instead."""
+    if view is None:
+        return _row_bytes(schema, profile) * cap
+    cols, side = view
+    return _row_bytes({c: d for c, d in schema.items() if c not in cols},
+                      profile) * cap + side
+
+
 def phys_peak_memory(pplan, catalog: ir.Catalog,
                      profile: DeviceProfile) -> float:
     """Peak working set of a physical plan (max across operators), the
-    physical mirror of ``node_mem``."""
+    physical mirror of ``node_mem``.
+
+    A cross join's output is a view of its sides (``ops.cross_join``
+    broadcasts them; XLA fuses the broadcast into the row-local stages
+    that read it): a stage of the pipeline above it holds the columns it
+    computes at the product's capacity, and the sides. A Compact gathers
+    the view, and an operator other than a pipeline stage, or the plan's
+    output, materializes it. A Filter or Project stage also holds its
+    widest dense layer's input and output (``_dense_bytes``)."""
     from repro.core import physical as ph
     registry = pplan.registry
     peak = 0.0
@@ -483,24 +535,40 @@ def phys_peak_memory(pplan, catalog: ir.Catalog,
     def base(schema, cap) -> float:
         return _row_bytes(schema, profile) * cap
 
-    def visit(node) -> Tuple[Dict[str, int], int]:
+    def visit(node):
         nonlocal peak
-        child_infos = tuple(visit(c) for c in node.children())
+        kids = tuple(visit(c) for c in node.children())
+        for schema, cap, view in kids:
+            if view is not None and not isinstance(node, ph.PPipeline):
+                peak = max(peak, base(schema, cap))  # materialized
+        child_infos = tuple(k[:2] for k in kids)
         if isinstance(node, ph.PScan):
             schema, cap = _derive_info(node, registry, catalog, child_infos)
             peak = max(peak, base(schema, cap))
-            return schema, cap
+            return schema, cap, None
+        if isinstance(node, ph.PCrossJoin):
+            schema, cap = _derive_info(node, registry, catalog, child_infos)
+            side = sum(base(s, c) for s, c in child_infos)
+            peak = max(peak, side)
+            return schema, cap, (frozenset(schema), side)
         if isinstance(node, ph.PPipeline):
-            schema, cap = child_infos[0]
+            schema, cap, view = kids[0]
             for stage in node.stages:
+                act = _dense_bytes(_stage_exprs(stage), registry, cap, profile)
                 schema, cap = _stage_info(stage, schema, cap, registry)
-                m = base(schema, cap)
+                if view is not None:
+                    if isinstance(stage, ph.CompactStage):
+                        view = None
+                    elif isinstance(stage, ph.ProjectStage):
+                        made = {n for n, _ in stage.outputs}
+                        view = (view[0] & set(schema) - made, view[1])
+                m = _view_bytes(schema, cap, view, profile) + act
                 if isinstance(stage, ph.ProjectStage):
                     for _, e in stage.outputs:
                         for c in _calls(e):
                             m += registry.get(c.fn).param_bytes()
                 peak = max(peak, m)
-            return schema, cap
+            return schema, cap, view
         schema, cap = _derive_info(node, registry, catalog, child_infos)
         m = base(schema, cap)
         if isinstance(node, ph.PBlockedMatmul):
@@ -516,9 +584,11 @@ def phys_peak_memory(pplan, catalog: ir.Catalog,
             # device's block (in_capacity = per-device block) briefly
             m = base(schema, node.in_capacity * node.ways)
         peak = max(peak, m)
-        return schema, cap
+        return schema, cap, None
 
-    visit(pplan.root)
+    schema, cap, view = visit(pplan.root)
+    if view is not None:  # the result is materialized
+        peak = max(peak, base(schema, cap))
     return peak
 
 
@@ -528,10 +598,51 @@ def phys_peak_memory(pplan, catalog: ir.Catalog,
 
 def node_mem(node: ir.RelNode, registry: Registry, catalog: ir.Catalog,
              profile: DeviceProfile, phys: PhysMap = None) -> float:
-    """Peak bytes over the plan (max across operators)."""
-    peak = max((node_mem(c, registry, catalog, profile, phys)
-                for c in node.children()), default=0.0)
-    return max(peak, _local_mem(node, registry, catalog, profile, phys))
+    """Peak bytes over the plan (max across operators); a cross join's
+    output is a view of its sides, as in ``phys_peak_memory``."""
+    peak, view = _mem_walk(node, registry, catalog, profile, phys)
+    if view is not None:  # the result is materialized
+        peak = max(peak, _out_bytes(node, registry, catalog, profile))
+    return peak
+
+
+def _out_bytes(node, registry, catalog, profile) -> float:
+    out = ir.infer(node, registry, catalog)
+    return _row_bytes(out.schema, profile) * out.capacity
+
+
+def _mem_walk(node, registry, catalog, profile, phys):
+    """(peak bytes of the subtree, the view its output is: None, or the
+    cross join's columns it still holds unmaterialized and their sides'
+    bytes)."""
+    kids = [_mem_walk(c, registry, catalog, profile, phys)
+            for c in node.children()]
+    peak = max((k[0] for k in kids), default=0.0)
+    view = kids[0][1] if kids else None
+    if isinstance(node, (ir.Filter, ir.Project)):
+        ci = ir.infer(node.child, registry, catalog)
+        out = ir.infer(node, registry, catalog)
+        m = _dense_bytes(_stage_exprs(node), registry, ci.capacity, profile)
+        if isinstance(node, ir.Project):
+            for _, e in node.outputs:
+                for c in _calls(e):
+                    m += registry.get(c.fn).param_bytes()
+        if view is not None:
+            made = ({n for n, _ in node.outputs}
+                    if isinstance(node, ir.Project) else set())
+            view = (view[0] & set(out.schema) - made, view[1])
+        return max(peak, m + _view_bytes(out.schema, out.capacity, view,
+                                         profile)), view
+    for c, (_, v) in zip(node.children(), kids):
+        if v is not None and not isinstance(node, ir.Compact):
+            # consumed by a non-row-local operator (a Compact gathers it)
+            peak = max(peak, _out_bytes(c, registry, catalog, profile))
+    if isinstance(node, ir.CrossJoin):
+        side = sum(_out_bytes(c, registry, catalog, profile)
+                   for c in node.children())
+        out = ir.infer(node, registry, catalog)
+        return max(peak, side), (frozenset(out.schema), side)
+    return max(peak, _local_mem(node, registry, catalog, profile, phys)), None
 
 
 def _local_mem(node, registry, catalog, profile, phys=None):
@@ -571,12 +682,18 @@ def plan_peak_memory(plan, catalog: ir.Catalog,
 # the single entry point
 # ---------------------------------------------------------------------------
 
+OVER_BUDGET = 1e6
+
+
 def plan_cost(plan, catalog: ir.Catalog,
               profile: DeviceProfile | None = None,
               memory_budget: float | None = None) -> float:
     """Analytic plan latency — logical ``ir.Plan`` or physical
-    ``PhysicalPlan`` alike; plans whose working set exceeds the memory
-    budget pay a paging/OOM penalty (mirrors the paper's OOM failures).
+    ``PhysicalPlan`` alike; a plan whose working set exceeds the memory
+    budget would not run (the paper's OOM failures): it costs
+    ``OVER_BUDGET`` times its time times its peak over the budget, so that
+    it loses to any plan that fits, and among those that do not the search
+    is still drawn toward a smaller overshoot.
     ``memory_budget`` defaults to the profile's own per-device budget; a
     non-finite budget is explicitly unlimited (callers that already
     checked the peak themselves — costed lowering's hard gate — pass
@@ -593,7 +710,8 @@ def plan_cost(plan, catalog: ir.Catalog,
     if memory_budget is not None and np.isfinite(memory_budget):
         peak = plan_peak_memory(plan, catalog, profile)
         if peak > memory_budget:
-            t *= 1.0 + 20.0 * (peak / memory_budget - 1.0)
+            t *= OVER_BUDGET * peak / memory_budget
+            tracing.count("cost.demoted")
     return t
 
 

@@ -26,6 +26,7 @@ import logging
 import math
 from typing import Dict, Optional
 
+from repro import tracing
 from repro.core import cost, ir, stage_graph
 from repro.core import physical as ph
 
@@ -84,6 +85,7 @@ def lower_costed(plan: ir.Plan, catalog: ir.Catalog, *,
         if memory_budget is not None:
             if cost.phys_peak_memory(pp, catalog, profile) > memory_budget:
                 pruned["n"] += 1
+                tracing.count("lowering.pruned")
                 return math.inf
         return cost.plan_cost(pp, catalog, profile, memory_budget=math.inf)
 

@@ -203,6 +203,7 @@ class PlanCache:
 
     def _fell_back(self, what: str) -> None:
         self.fallbacks[what] = self.fallbacks.get(what, 0) + 1
+        tracing.count("fallback." + what)
 
     @property
     def stats(self) -> CacheStats:
@@ -314,7 +315,8 @@ class PlanCache:
                 self.traces += 1  # python side effect: runs only while tracing
                 return ph.run(pplan, tables)
 
-            jfn = _jit_named(traced, "plan_single", key, 1)
+            jfn = _jit_named(traced, "plan_single", key, 1,
+                             low.peak_memory)
 
             def fn(tables: Dict[str, Table]) -> Table:
                 # normalize to the scanned tables only: full-catalog and
@@ -356,13 +358,15 @@ class PlanCache:
             base = f"{base}#be={backend}"
         low = self._lowered_for(plan, catalog, base, backend)
         key = base + "#cl=" + low.signature + f"#vmap={batch_size}"
-        return self._get_or_compile_stacked(key, low.plan, plan, catalog,
-                                            batch_size, kind="batched")
+        return self._get_or_compile_stacked(
+            key, low.plan, plan, catalog, batch_size, kind="batched",
+            analytic_peak=low.peak_memory * batch_size)
 
     def _get_or_compile_stacked(self, key: str, pplan, plan: ir.Plan,
                                 catalog: ir.Catalog, batch_size: int, *,
                                 kind: str,
-                                wrap: Optional[Callable] = None):
+                                wrap: Optional[Callable] = None,
+                                analytic_peak: float = 0.0):
         """Shared body of the batched/sharded entries: stack ``batch_size``
         same-schema table dicts on a leading axis, run the vmapped plan body
         (optionally transformed by ``wrap``, e.g. shard_map over a mesh),
@@ -389,7 +393,7 @@ class PlanCache:
                                  for i in range(batch_size))
 
             jfn = _jit_named(traced, f"plan_{kind}{batch_size}", key,
-                             batch_size)
+                             batch_size, analytic_peak)
 
             def fn(tables_seq):
                 if len(tables_seq) != batch_size:
@@ -438,7 +442,8 @@ class PlanCache:
                + f"#mesh={mesh_util.mesh_signature(mesh)}")
         return self._get_or_compile_stacked(
             key, low.plan, plan, catalog, batch_size, kind="sharded",
-            wrap=lambda body: mesh_util.shard_batch(body, mesh))
+            wrap=lambda body: mesh_util.shard_batch(body, mesh),
+            analytic_peak=low.peak_memory * batch_size)
 
     def get_or_compile_partitioned(self, plan: ir.Plan, catalog: ir.Catalog,
                                    mesh, *, backend: Optional[str] = None,
@@ -495,7 +500,7 @@ class PlanCache:
                 return ph.run(pplan, tables, axis=mesh_util.DATA_AXIS)
 
             jfn = _jit_named(mesh_util.shard_replicated(traced, mesh),
-                             "plan_partitioned", key, 1)
+                             "plan_partitioned", key, 1, low.peak_memory)
 
             def fn(tables: Dict[str, Table]) -> Table:
                 return jfn({k: tables[k] for k in names})
@@ -508,12 +513,14 @@ class PlanCache:
         return self.get_or_compile(plan, catalog)(dict(catalog.tables))
 
 
-def _jit_named(fun: Callable, name: str, key: str, batch_size: int):
+def _jit_named(fun: Callable, name: str, key: str, batch_size: int,
+               analytic_peak: float):
     """``jax.jit(fun)`` compiled as module ``jit_<name>``, so that a trace's
     modules tell the single, batched, sharded and partitioned executables
     apart (the name is also part of the persistent compile cache's key).
-    The first call records the executable's operator scopes
-    (``tracing.record_executable``); the lowering and the compiled
+    The first call records the executable's operator scopes and device
+    bytes beside ``analytic_peak``, the planner's for the same physical
+    plan (``tracing.record_executable``); the lowering and the compiled
     executable are then JAX's in-memory cache hits."""
     def named(*args):
         return fun(*args)
@@ -527,7 +534,8 @@ def _jit_named(fun: Callable, name: str, key: str, batch_size: int):
         if not recorded:
             recorded.append(key)
             tracing.record_executable(key, batch_size,
-                                      jfn.lower(*args).compile())
+                                      jfn.lower(*args).compile(),
+                                      analytic_peak)
         return out
 
     call.lower = jfn.lower

@@ -139,6 +139,18 @@ def residual_graph(g: MLGraph, cut: int, new_input: int) -> MLGraph:
     return MLGraph(nodes=nodes, out=g.out, n_inputs=new_input + 1)
 
 
+def drop_unused_inputs(g: MLGraph) -> Tuple[MLGraph, List[int]]:
+    """``g`` without the inputs no node reads, the rest renumbered in
+    order, and the old index of each input kept."""
+    used = sorted({r[1] for n in g.nodes for r in n.args if r[0] == "in"})
+    remap = {old: new for new, old in enumerate(used)}
+    nodes = [MLNode(id=n.id, atom=n.atom,
+                    args=tuple(("in", remap[r[1]]) if r[0] == "in" else r
+                               for r in n.args))
+             for n in g.nodes]
+    return MLGraph(nodes=nodes, out=g.out, n_inputs=len(used)), used
+
+
 def replace_graph_node(g: MLGraph, nid: int, new_nodes: List[MLNode],
                        new_out: int) -> MLGraph:
     """Replace node nid with a set of new nodes; refs to nid point at new_out."""

@@ -66,14 +66,7 @@ class SplitDisjoint(Rule):
         # prune inputs the residual no longer touches (their argument
         # expressions — possibly expensive nested calls — must not be
         # evaluated at this level anymore)
-        used = sorted({r[1] for n in res.nodes for r in n.args if r[0] == "in"})
-        remap = {old: new for new, old in enumerate(used)}
-        res_nodes = [
-            type(n)(id=n.id, atom=n.atom,
-                    args=tuple(("in", remap[r[1]]) if r[0] == "in" else r
-                               for r in n.args))
-            for n in res.nodes]
-        res = type(res)(nodes=res_nodes, out=res.out, n_inputs=len(used))
+        res, used = base.drop_unused_inputs(res)
         sub_name = registry.fresh_name(fn.name + "_sub")
         res_name = registry.fresh_name(fn.name + "_res")
         registry.replace(MLFunction(name=sub_name, graph=sub, n_inputs=sub.n_inputs))

@@ -245,4 +245,8 @@ class QueryServer:
             # requests served by a single-device executable they did not
             # ask for, and over-budget lowerings (see PlanCache.fallbacks)
             "fallbacks": dict(self.cache.fallbacks),
+            # process-wide: lowering candidates the memory budget pruned,
+            # plans the over-budget penalty priced up (tracing.counts)
+            "budget": {k: tracing.counts.get(k, 0)
+                       for k in ("lowering.pruned", "cost.demoted")},
         }

@@ -31,6 +31,18 @@ PUBLISHED = frozenset({"optimizer.cost"})
 
 # seconds inside each published span, over the process's life
 published_s: Dict[str, float] = defaultdict(float)
+# events counted over the process's life (``count``): "lowering.pruned",
+# lowering candidates the memory budget rejected; "cost.demoted", plans the
+# over-budget penalty of ``cost.plan_cost`` priced up; "fallback.<what>",
+# requests a plan cache served otherwise than asked (``PlanCache.fallbacks``)
+counts: Dict[str, int] = defaultdict(int)
+
+
+def count(name: str) -> None:
+    """Count one ``name`` event, also on JAX's monitoring bus as
+    ``/repro/<name>``."""
+    counts[name] += 1
+    jax.monitoring.record_event("/repro/" + name)
 
 
 class _Published:
@@ -176,24 +188,37 @@ def operators(hlo_text: str) -> Dict[str, OpScope]:
 
 
 class Executable(NamedTuple):
-    """A compiled plan executable as ``record_executable`` keeps it."""
+    """A compiled plan executable as ``record_executable`` keeps it: the
+    compiler's device bytes (``memory_analysis``: temporaries, arguments,
+    outputs) beside the planner's analytic peak for the same physical plan
+    (``cost.phys_peak_memory``, for the whole batch)."""
     module: str
     batch_size: int
     ops: Dict[str, OpScope]
+    temp_bytes: int = 0
+    argument_bytes: int = 0
+    output_bytes: int = 0
+    analytic_peak_bytes: float = 0.0
 
 
 _EXECUTABLES: "OrderedDict[str, Executable]" = OrderedDict()
 MAX_EXECUTABLES = 64
 
 
-def record_executable(key: str, batch_size: int, compiled) -> None:
-    """Keep the operator map of a compiled executable under its cache key.
-    The map is per process, as the HLO names a profiler reports are; the
-    oldest of more than ``MAX_EXECUTABLES`` entries is dropped."""
+def record_executable(key: str, batch_size: int, compiled,
+                      analytic_peak: float = 0.0) -> None:
+    """Keep the operator map and device bytes of a compiled executable
+    under its cache key. The map is per process, as the HLO names a
+    profiler reports are; the oldest of more than ``MAX_EXECUTABLES``
+    entries is dropped."""
     text = compiled.as_text()
     m = re.match(r"HloModule\s+([\w.\-]+)", text)
-    _EXECUTABLES[key] = Executable(m.group(1) if m else "", batch_size,
-                                   operators(text))
+    ma = compiled.memory_analysis()
+    _EXECUTABLES[key] = Executable(
+        m.group(1) if m else "", batch_size, operators(text),
+        getattr(ma, "temp_size_in_bytes", 0),
+        getattr(ma, "argument_size_in_bytes", 0),
+        getattr(ma, "output_size_in_bytes", 0), float(analytic_peak))
     _EXECUTABLES.move_to_end(key)
     while len(_EXECUTABLES) > MAX_EXECUTABLES:
         _EXECUTABLES.popitem(last=False)

@@ -270,3 +270,40 @@ def test_breakdown_scaled_rides_the_batch_axis():
     assert s.hbm_bytes == pytest.approx(8 * b.hbm_bytes)
     assert s.param_bytes == b.param_bytes  # weights stream once
     assert s.n_ops == b.n_ops
+
+
+# ---------------------------------------------------------------------------
+# the over-budget penalty
+# ---------------------------------------------------------------------------
+
+def test_where_no_plan_fits_the_smaller_overshoot_wins():
+    """Two plans over the budget: the one with the smaller peak costs
+    less, though it is the slower, so that a search keeps a gradient
+    toward a plan that fits; each costs more than either would if it fit."""
+    from repro.relational.table import Table
+    reg = Registry()
+    reg.register(builders.ffnn("wide", [64, 4096, 1]))
+    reg.register(builders.ffnn("deep", [64, 512, 512, 512, 512, 1]))
+    catalog = ir.Catalog()
+    catalog.add("t", Table.from_columns(
+        {"x": np.zeros((8192, 64), np.float32)}))
+
+    def plan(fn):
+        return ir.Plan(ir.Project(ir.Scan("t"), outputs=(
+            ("y", ir.Call(fn, (ir.Col("x"),))),), keep=()), reg)
+
+    plans = {fn: plan(fn) for fn in ("wide", "deep")}
+    profile = cost.DeviceProfile.detect()
+
+    def each(f):
+        return {fn: f(p) for fn, p in plans.items()}
+
+    peak = each(lambda p: cost.plan_peak_memory(p, catalog, profile))
+    t = each(lambda p: cost.plan_cost(p, catalog, profile,
+                                      memory_budget=np.inf))
+    assert peak["deep"] < peak["wide"] and t["deep"] > t["wide"]
+    budget = peak["deep"] / 2
+    over = each(lambda p: cost.plan_cost(p, catalog, profile,
+                                         memory_budget=budget))
+    assert over["deep"] < over["wide"]
+    assert min(over.values()) > max(t.values())

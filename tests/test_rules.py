@@ -130,3 +130,29 @@ def test_prop_random_rule_sequences(setup, seed):
         cur = ALL_RULES[name].apply(cur, cat, cfg)
     out = execute(cur, cat).canonical()
     check_equal(base, out, f"seq seed={seed}")
+
+
+def test_r21_offers_its_cross_join_config_exactly_where_it_rewrites(setup):
+    """R2-1's cheap check over a cross join (a matmul over a concat of both
+    sides above it) agrees with the separation itself, on the query above,
+    the workloads, and each plan one rule step from them."""
+    from repro.core.rules import base, o2
+    from repro.data import workloads
+    roots = [setup[:2]] + [
+        (w.plan, w.catalog) for w in (workloads.ALL_WORKLOADS[n](scale=0.3)
+                                      for n in sorted(workloads.ALL_WORKLOADS))
+        if any(isinstance(n, ir.CrossJoin) for n in ir.walk(w.plan.root))]
+    cases = list(roots)
+    for plan, cat in roots:
+        for rule in ALL_RULES.values():
+            cases += [(rule.apply(plan, cat, c), cat)
+                      for c in rule.configs(plan, cat)[:1]]
+    seen = set()
+    for plan, cat in cases:
+        for p in base.all_paths(plan.root):
+            if isinstance(base.node_at(plan.root, p), ir.CrossJoin):
+                offered = o2._factorizes_across(plan, cat, p)
+                assert offered == (
+                    o2._separate_cross_join(plan, cat, p) is not None), p
+                seen.add(offered)
+    assert seen == {True, False}

@@ -10,7 +10,11 @@ programs that do not fit the device.
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
+import dataclasses
+import importlib.util
+import json
 import os
+from pathlib import Path
 
 import pytest
 
@@ -18,8 +22,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import physical as ph
+from repro.core import cost, ir, physical as ph
+from repro.core.costed_lowering import lower_costed
 from repro.core.lowering import lower
+from repro.core.planner import analytic_cost_fn, optimize_vanilla_mcts
 from repro.core.plan_cache import scan_table_names, stack_tables
 from repro.core.rules import ALL_RULES
 from repro.data import workloads
@@ -29,6 +35,10 @@ from repro.kernels.decision_forest import ops as df_ops
 from repro.kernels.fused_dense import ops as fd_ops
 
 HBM_BYTES = 16e9  # one v5e chip
+BUDGET = 15.75e9  # what one chip's allocator hands out, about
+# the planner's analytic peak lies within this factor of the compiled
+# program's device bytes (temporaries, arguments and outputs)
+PEAK_FACTOR = 4.0
 # rec_q1 at scale 60: 6,000 users x 512 compacted movies, scored per pair by
 # the user tower's first layer (64 -> 300)
 PAIRS, TOWER_IN, TOWER_HIDDEN = 6000 * 512, 64, 300
@@ -141,3 +151,37 @@ def test_analytics_q1_forest_kernel_plan_at_published_size(batch, one_chip,
             stack_tables(list(ts))))
         _assert_compiled_kernel(
             fn, tuple(_shapes(tables, one_chip) for _ in range(batch)))
+
+
+def test_rec_q2_plan_at_published_size(one_chip, compiled_for_tpu):
+    """The plan the optimizer chooses for recommendation Q2 at MovieLens-1M
+    size (``bench/configs/movielens1m_q2.py``), costed as on a v5e against
+    its budget, compiles; the compiler's temporaries fit; the analytic peak
+    is within ``PEAK_FACTOR`` of the compiled bytes; no tag column rides
+    the 6,040 x 2,048 pairs."""
+    configs = Path(__file__).resolve().parents[1] / "bench" / "configs"
+    spec = importlib.util.spec_from_file_location(
+        "q2_compile", configs / "movielens1m_q2.py")
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    cfg = json.loads((configs / "movielens1m_q2.json").read_text())
+    built = gen.build(cfg, 11, 1)
+    catalog = built["catalog"]
+    profile = dataclasses.replace(cost.TPU_PROFILE, memory_budget=BUDGET)
+    plan, _ = optimize_vanilla_mcts(
+        built["plan"], catalog,
+        cost_fn=analytic_cost_fn(catalog, profile),
+        iterations=cfg["mcts_iterations"], seed=cfg["mcts_seed"])
+    low = lower_costed(plan, catalog, profile=profile)
+    assert not low.budget_pruned_all and low.peak_memory <= BUDGET
+    join = next(n for n in ir.walk(plan.root) if isinstance(n, ir.CrossJoin))
+    assert "mt_relevance" not in ir.infer(join, plan.registry, catalog).schema
+    tables = {k: catalog.tables[k] for k in scan_table_names(plan)}
+    compiled = jax.jit(lambda t: ph.run(low.plan, t)).lower(
+        _shapes(tables, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < BUDGET
+    used = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+    assert used / PEAK_FACTOR <= low.peak_memory <= used * PEAK_FACTOR, (
+        low.peak_memory, used)

@@ -154,3 +154,38 @@ def test_search_publishes_each_cost_call_on_the_monitoring_bus():
     assert calls and len(seen) == len(calls)
     assert tracing.published_s["optimizer.cost"] - before == pytest.approx(
         sum(seen))
+
+
+def test_an_executable_records_its_device_bytes_beside_the_analytic_peak():
+    w = workloads.rec_q1(scale=SCALE)
+    cache = PlanCache()
+    cache.get_or_compile(w.plan, w.catalog)(dict(w.catalog.tables))
+    key = cache.key(w.plan, w.catalog)
+    exe = tracing._EXECUTABLES[key]
+    low = cache._lowered_for(w.plan, w.catalog, cache.base_key(w.plan,
+                                                               w.catalog),
+                             None)
+    assert exe.analytic_peak_bytes == low.peak_memory > 0
+    assert exe.argument_bytes > 0 and exe.output_bytes > 0
+    assert exe.temp_bytes >= 0
+
+
+def test_budget_counters_move_when_a_budget_is_below_a_plan_peak():
+    from repro.core import cost, costed_lowering
+    w = workloads.rec_q1(scale=SCALE)
+    profile = cost.DeviceProfile.detect()
+    peak = cost.plan_peak_memory(w.plan, w.catalog, profile)
+    pruned, demoted = (tracing.counts["lowering.pruned"],
+                       tracing.counts["cost.demoted"])
+    cost.plan_cost(w.plan, w.catalog, profile, memory_budget=peak * 2)
+    assert tracing.counts["cost.demoted"] == demoted
+    fits = cost.plan_cost(w.plan, w.catalog, profile, memory_budget=peak * 2)
+    over = cost.plan_cost(w.plan, w.catalog, profile, memory_budget=peak / 2)
+    assert tracing.counts["cost.demoted"] == demoted + 1
+    assert over == pytest.approx(fits * cost.OVER_BUDGET * 2)
+    costed_lowering.lower_costed(w.plan, w.catalog, profile=profile,
+                                 memory_budget=64.0)
+    assert tracing.counts["lowering.pruned"] > pruned
+    stats = QueryServer().stats()["budget"]
+    assert stats == {"lowering.pruned": tracing.counts["lowering.pruned"],
+                     "cost.demoted": tracing.counts["cost.demoted"]}
