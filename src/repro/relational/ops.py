@@ -122,28 +122,30 @@ def cross_join(a: Table, b: Table, aprefix: str = "", bprefix: str = "") -> Tabl
 
 _AGG_KINDS = ("sum", "mean", "count", "min", "max")
 
+# per kind: the scan's combining op and the value of a group slot left empty
+_AGG_SCAN = {"sum": (jnp.add, 0.0), "mean": (jnp.add, 0.0),
+             "min": (jnp.minimum, jnp.inf), "max": (jnp.maximum, -jnp.inf)}
 
-def _dense_group_ids(keys: jax.Array, valid: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Map arbitrary int32 keys (valid rows) to dense ids [0..G).
 
-    Returns (gid[N] with invalid rows mapped to a padding id, rep_key[N]
-    giving the key value for each dense id slot, num_groups scalar).
+def _segmented_scan(x: jax.Array, start: jax.Array, op, empty) -> jax.Array:
+    """Inclusive scan of ``op`` down axis 0 of ``x``, restarting where ``start``.
+
+    Log-step (Hillis-Steele): the pass for step ``k`` combines each row with
+    the one ``k`` rows above it unless a segment starts between them, so a
+    row's result combines only rows of its own segment and its rounding grows
+    with the segment, not with everything before it. Each pass is one
+    elementwise fusion over the rows; ``empty`` is ``op``'s identity.
     """
-    n = keys.shape[0]
-    km = jnp.where(valid, keys.astype(jnp.int32), _INT_SENTINEL)
-    order = jnp.argsort(km)
-    s = km[order]
-    newseg = jnp.concatenate([jnp.array([True]), s[1:] != s[:-1]])
-    newseg = newseg & (s != _INT_SENTINEL)
-    gid_sorted = jnp.cumsum(newseg.astype(jnp.int32)) - 1
-    gid_sorted = jnp.where(s == _INT_SENTINEL, n, gid_sorted)  # pad bucket
-    inv = jnp.argsort(order)
-    gid = gid_sorted[inv]
-    num_groups = jnp.sum(newseg.astype(jnp.int32))
-    # representative key per dense id (first occurrence in sorted order)
-    rep = jnp.full((n,), _INT_SENTINEL, jnp.int32)
-    rep = rep.at[jnp.where(newseg, gid_sorted, n)].set(s, mode="drop")
-    return gid, rep, num_groups
+    n = x.shape[0]
+    k = 1
+    while k < n:
+        up = jnp.pad(x[:-k], ((k, 0),) + ((0, 0),) * (x.ndim - 1),
+                     constant_values=empty)
+        keep = start.reshape(start.shape + (1,) * (x.ndim - 1))
+        x = jnp.where(keep, x, op(up, x))
+        start = start | jnp.pad(start[:-k], (k, 0), constant_values=True)
+        k *= 2
+    return x
 
 
 def aggregate(t: Table, key: str, aggs: Mapping[str, Tuple[str, str]],
@@ -151,43 +153,59 @@ def aggregate(t: Table, key: str, aggs: Mapping[str, Tuple[str, str]],
     """Group by ``key``; ``aggs`` maps out_name -> (kind, in_column).
 
     kind in {sum, mean, count, min, max}. Output capacity = ``num_groups``
-    (static upper bound on distinct keys; rows beyond the bound are dropped).
-    The group key is emitted under its original name.
+    (static upper bound on distinct keys; the smallest ``num_groups`` keys
+    are kept, rows of larger keys are dropped). Groups come out ascending by
+    key, padded with ``_INT_SENTINEL`` keys and invalid rows. The group key
+    is emitted under its original name.
+
+    One sort of the masked keys carries the 1-D value columns (wider columns
+    follow a carried row index); a group's rows are then contiguous, so each
+    result is a segmented scan read at the group's last row, found by a
+    ``num_groups``-sized search. No pass scatters or gathers over the rows.
     """
-    gid, rep, ng = _dense_group_ids(t[key], t.valid)
-    if rep.shape[0] < num_groups:  # more group slots than input rows
-        rep = jnp.pad(rep, (0, num_groups - rep.shape[0]),
-                      constant_values=_INT_SENTINEL)
-    seg = jnp.where(gid < num_groups, gid, num_groups)  # overflow+padding bucket
-    ones = t.valid.astype(jnp.float32)
-    counts = jax.ops.segment_sum(ones, seg, num_segments=num_groups + 1)[:num_groups]
-    cols: Dict[str, jax.Array] = {key: rep[:num_groups]}
-    for out_name, (kind, in_col) in aggs.items():
+    for kind, _ in aggs.values():
         if kind not in _AGG_KINDS:
             raise ValueError(f"unknown agg kind {kind}")
+    n = t.capacity
+    km = jnp.where(t.valid, t[key].astype(jnp.int32), _INT_SENTINEL)
+    in_cols = sorted({c for kind, c in aggs.values() if kind != "count"})
+    flat = [c for c in in_cols if t[c].ndim == 1]
+    wide = [c for c in in_cols if t[c].ndim > 1]
+    operands = [km] + [jnp.where(t.valid, t[c].astype(jnp.float32), 0.0)
+                       for c in flat]
+    if wide:
+        operands.append(jnp.arange(n, dtype=jnp.int32))
+    out = jax.lax.sort(tuple(operands), num_keys=1, is_stable=False)
+    s = out[0]
+    xs = dict(zip(flat, out[1:]))
+    for c in wide:
+        xs[c] = t[c].astype(jnp.float32)[out[-1]]
+
+    live = s != _INT_SENTINEL  # invalid rows sort last
+    start = jnp.concatenate([jnp.array([True]), s[1:] != s[:-1]]) & live
+    # dead rows take the sentinel id, above every slot even where num_groups > n
+    gid = jnp.where(live, jnp.cumsum(start.astype(jnp.int32)) - 1,
+                    _INT_SENTINEL)
+    ng = jnp.sum(start.astype(jnp.int32))
+    # last row of each dense id in sorted order (-1 where no row is live)
+    last = jnp.searchsorted(gid, jnp.arange(num_groups, dtype=jnp.int32),
+                            side="right") - 1
+    at = jnp.clip(last, 0, n - 1)
+    has = jnp.arange(num_groups) < jnp.minimum(ng, num_groups)
+    counts = jnp.diff(last, prepend=-1).astype(jnp.float32)
+    cols: Dict[str, jax.Array] = {key: jnp.where(has, s[at], _INT_SENTINEL)}
+    for out_name, (kind, in_col) in aggs.items():
         if kind == "count":
             cols[out_name] = counts
             continue
-        x = t[in_col].astype(jnp.float32)
-        mask = t.valid
-        if x.ndim > 1:
-            mask = mask.reshape((-1,) + (1,) * (x.ndim - 1))
-        if kind in ("sum", "mean"):
-            xm = jnp.where(mask, x, 0.0)
-            s = jax.ops.segment_sum(xm, seg, num_segments=num_groups + 1)[:num_groups]
-            if kind == "mean":
-                denom = jnp.maximum(counts, 1.0)
-                denom = denom.reshape((-1,) + (1,) * (x.ndim - 1)) if x.ndim > 1 else denom
-                s = s / denom
-            cols[out_name] = s
-        elif kind == "min":
-            xm = jnp.where(mask, x, jnp.inf)
-            cols[out_name] = jax.ops.segment_min(xm, seg, num_segments=num_groups + 1)[:num_groups]
-        else:  # max
-            xm = jnp.where(mask, x, -jnp.inf)
-            cols[out_name] = jax.ops.segment_max(xm, seg, num_segments=num_groups + 1)[:num_groups]
-    valid = jnp.arange(num_groups) < jnp.minimum(ng, num_groups)
-    return Table(columns=cols, valid=valid)
+        op, empty = _AGG_SCAN[kind]
+        r = _segmented_scan(xs[in_col], start, op, empty)[at]
+        grow = (-1,) + (1,) * (r.ndim - 1)
+        r = jnp.where(has.reshape(grow), r, empty)
+        if kind == "mean":
+            r = r / jnp.maximum(counts, 1.0).reshape(grow)
+        cols[out_name] = r
+    return Table(columns=cols, valid=has)
 
 
 # ---------------------------------------------------------------------------

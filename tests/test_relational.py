@@ -1,4 +1,8 @@
 """Relational engine vs numpy oracle — unit + hypothesis property tests."""
+import functools
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -102,6 +106,122 @@ def test_aggregate_masked_rows_excluded():
     g = ops.aggregate(t, "k", {"s": ("sum", "x")}, num_groups=4)
     out = g.canonical()
     np.testing.assert_allclose(out["s"], [1.0, 2.0])
+
+
+_AGGS = {"s": ("sum", "x"), "m": ("mean", "x"), "c": ("count", "x"),
+         "mn": ("min", "x"), "mx": ("max", "x"), "vm": ("mean", "v")}
+_SENT = np.iinfo(np.int32).max
+_KEY_POOLS = {
+    "small": np.arange(12),
+    "wide": np.array([-2**31, -10**9, -7, 0, 3, 10**9, 2**31 - 2]),
+}
+
+
+def _agg_case(rng, n, pool, p_valid):
+    keys = rng.choice(_KEY_POOLS[pool], n).astype(np.int32)
+    x = rng.integers(-50, 50, n).astype(np.float32) / 4
+    v = rng.standard_normal((n, 3)).astype(np.float32)
+    valid = rng.random(n) < p_valid
+    return keys, x, v, valid
+
+
+def _check_aggregate(out, keys, x, v, valid, num_groups):
+    """``out`` (numpy columns + valid) against the oracle over valid rows.
+
+    The smallest ``num_groups`` keys come first in ascending order; the
+    remaining slots hold the sentinel key, zero sums and counts, and invalid.
+    """
+    ref = oracle.aggregate({"k": keys[valid], "x": x[valid], "v": v[valid]},
+                           "k", _AGGS)
+    g = min(len(ref["k"]), num_groups)
+    np.testing.assert_array_equal(out["valid"], np.arange(num_groups) < g)
+    np.testing.assert_array_equal(out["k"][:g], ref["k"][:g])
+    np.testing.assert_array_equal(out["k"][g:], _SENT)
+    for name in _AGGS:
+        want = np.reshape(ref[name][:g], out[name][:g].shape)  # empty: (0,)
+        np.testing.assert_allclose(out[name][:g], want, rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
+    for name, empty in (("s", 0.0), ("m", 0.0), ("c", 0.0), ("mn", np.inf),
+                        ("mx", -np.inf), ("vm", 0.0)):
+        np.testing.assert_array_equal(out[name][g:], empty, err_msg=name)
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _aggregate_columns(keys, x, v, valid, num_groups):
+    t = Table(columns={"k": keys, "x": x, "v": v}, valid=valid)
+    g = ops.aggregate(t, "k", _AGGS, num_groups)
+    return dict(g.columns, valid=g.valid)
+
+
+@pytest.mark.parametrize("n,pool,p_valid,num_groups", [
+    (60, "small", 1.0, 16),   # every kind, all rows valid
+    (60, "small", 0.6, 16),   # invalid rows excluded
+    (40, "wide", 0.8, 8),     # negative and very large keys
+    (80, "small", 0.9, 5),    # more distinct keys than slots: smallest kept
+    (7, "small", 1.0, 20),    # more slots than rows
+    (9, "small", 0.5, 20),    # more slots than rows, some rows invalid
+    (30, "wide", 0.0, 6),     # no valid row
+], ids=["kinds", "invalid", "wide_keys", "overflow", "slots_gt_rows",
+        "slots_gt_rows_invalid", "all_invalid"])
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "vmap3"])
+def test_aggregate_matches_oracle_cases(n, pool, p_valid, num_groups,
+                                        batched):
+    rng = np.random.default_rng(n * 7 + num_groups)
+    if not batched:
+        case = _agg_case(rng, n, pool, p_valid)
+        out = _aggregate_columns(*map(jnp.asarray, case), num_groups)
+        _check_aggregate(jax.tree.map(np.asarray, out), *case, num_groups)
+        return
+    cases = [_agg_case(rng, n, pool, p_valid) for _ in range(3)]
+    stacked = [jnp.asarray(np.stack(col)) for col in zip(*cases)]
+    out = jax.vmap(lambda *a: _aggregate_columns(*a, num_groups))(*stacked)
+    for i, case in enumerate(cases):
+        _check_aggregate({k: np.asarray(c[i]) for k, c in out.items()},
+                         *case, num_groups)
+
+
+def test_aggregate_sum_precision_is_per_group():
+    # a group of values near 1.0 sorted after groups near 1e6: its sum's
+    # error follows its own magnitude, not the running total before it
+    rng = np.random.default_rng(11)
+    big = 1e6 + rng.random(3000) * 1000
+    small = 1.0 + rng.random(500) * 1e-3
+    keys = np.concatenate([np.repeat([0, 1, 2], 1000), np.full(500, 9)])
+    x = np.concatenate([big, small]).astype(np.float32)
+    order = rng.permutation(len(x))
+    t = Table.from_columns({"k": jnp.asarray(keys[order], jnp.int32),
+                            "x": jnp.asarray(x[order])})
+    g = ops.aggregate(t, "k", {"s": ("sum", "x")}, num_groups=4)
+    want = x[keys == 9].astype(np.float64).sum()
+    got = float(np.asarray(g["s"])[3])
+    assert abs(got - want) <= 8 * np.finfo(np.float32).eps * want, (got, want)
+
+
+def _hlo_ops(compiled):
+    """Counts of sorts and scatters, and the elements each gather reads."""
+    txt = compiled.as_text()
+    count = {op: len(re.findall(rf"\s{op}\(", txt)) for op in ("sort", "scatter")}
+    gathers = [int(np.prod([int(d) for d in dims.split(",") if d]))
+               for dims in re.findall(r"=\s+\w+\[([0-9,]*)\]\S*\s+gather\(", txt)]
+    return count, gathers
+
+
+def test_aggregate_compiles_to_one_sort_and_no_scatter():
+    n, num_groups = 4096, 64
+    aggs = {k: a for k, a in _AGGS.items() if a[1] == "x"}
+
+    def agg(keys, x, valid):
+        t = Table(columns={"k": keys, "x": x}, valid=valid)
+        return ops.aggregate(t, "k", aggs, num_groups)
+
+    args = (jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32),
+            jnp.ones((n,), bool))
+    for batch, f, a in ((1, agg, args),
+                        (3, jax.vmap(agg), [jnp.stack([c] * 3) for c in args])):
+        count, gathers = _hlo_ops(jax.jit(f).lower(*a).compile())
+        assert count == {"sort": 1, "scatter": 0}, count
+        # every gather reads num_groups elements a request, none the n rows
+        assert gathers and max(gathers) <= batch * num_groups, gathers
 
 
 def test_union_all():
