@@ -5,11 +5,11 @@ the MCTS reward oracle (``planner.analytic_cost_fn`` / ``mcts.VanillaMCTS``),
 costed lowering (``core.costed_lowering`` scores physical candidates), the
 serving tier's batch-realization choice (``batched_plan_cost``), and the
 online feedback calibration (``fit_profile`` refits a ``DeviceProfile``
-against measured dispatch latencies). ``plan_cost`` accepts both the logical
-``ir.Plan`` and the physical ``physical.PhysicalPlan``; both walks share the
-same per-operator ``OpCost`` kernels, so there is exactly one set of cost
-formulas (a tree-order-lowered physical plan costs bit-identically to its
-logical tree).
+against measured dispatch latencies). Every entry point accepts both the
+logical ``ir.Plan`` and the physical ``physical.PhysicalPlan``: a logical plan
+is costed on its tree-order lowering (``lowering.lower(costed=False)``), so
+there is one walk for time (``phys_op_costs``) and one for memory
+(``phys_peak_memory``).
 
 Costs each operator by FLOPs + bytes moved against a device profile, using
 capacity (static shape) rather than live-row counts — on TPU the *capacity*
@@ -21,16 +21,13 @@ compiled plans; it is deliberately a separate estimator.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro import tracing
 from repro.core import ir
 from repro.mlfuncs.registry import Registry
-
-PhysMap = Optional[Mapping[str, ir.PhysConfig]]
-
 
 @dataclasses.dataclass
 class DeviceProfile:
@@ -264,69 +261,6 @@ def _forest_cost(fn, x_dim, capacity, cfg: ir.PhysConfig, profile) -> OpCost:
                   bw="vmem" if cfg.backend == "pallas" else "hbm")
 
 
-# ---------------------------------------------------------------------------
-# logical-plan walk
-# ---------------------------------------------------------------------------
-
-def node_cost(node: ir.RelNode, registry: Registry, catalog: ir.Catalog,
-              profile: DeviceProfile, phys: PhysMap = None) -> float:
-    """Recursive total plan cost in seconds (analytic)."""
-    total = sum(node_cost(c, registry, catalog, profile, phys)
-                for c in node.children())
-    oc = _node_op_cost(node, registry, catalog, profile, phys)
-    if oc is not None:
-        total += op_time(oc, profile)
-    return total
-
-
-def _node_op_cost(node: ir.RelNode, registry: Registry, catalog: ir.Catalog,
-                  profile: DeviceProfile, phys: PhysMap = None
-                  ) -> Optional[OpCost]:
-    if isinstance(node, ir.Scan):
-        return None
-    if isinstance(node, ir.Filter):
-        ci = ir.infer(node.child, registry, catalog)
-        return _filter_cost(ir.expr_flops(node.pred, ci.schema, registry),
-                            ci.schema, ci.capacity, profile)
-    if isinstance(node, ir.Compact):
-        ci = ir.infer(node.child, registry, catalog)
-        return _compact_cost(ci.schema, ci.capacity, node.capacity, profile)
-    if isinstance(node, ir.Project):
-        ci = ir.infer(node.child, registry, catalog)
-        fl = sum(ir.expr_flops(e, ci.schema, registry) for _, e in node.outputs)
-        out = ir.infer(node, registry, catalog)
-        # parameter traffic: weights stream from HBM once per call
-        pb = 0.0
-        for _, e in node.outputs:
-            for c in _calls(e):
-                pb += registry.get(c.fn).param_bytes()
-        return _project_cost(fl, ci.schema, out.schema, pb, ci.capacity,
-                             profile)
-    if isinstance(node, ir.Join):
-        li = ir.infer(node.left, registry, catalog)
-        ri = ir.infer(node.right, registry, catalog)
-        out = ir.infer(node, registry, catalog)
-        return _join_cost(li.schema, li.capacity, ri.schema, ri.capacity,
-                          out.schema, out.capacity, profile)
-    if isinstance(node, ir.CrossJoin):
-        out = ir.infer(node, registry, catalog)
-        return _crossjoin_cost(out.schema, out.capacity, profile)
-    if isinstance(node, ir.Aggregate):
-        ci = ir.infer(node.child, registry, catalog)
-        return _aggregate_cost(ci.schema, ci.capacity, len(node.aggs), profile)
-    if isinstance(node, ir.BlockedMatmul):
-        ci = ir.infer(node.child, registry, catalog)
-        return _matmul_cost(registry.get(node.fn), ci.schema[node.x_col],
-                            ci.capacity, ir.resolve_phys(node, phys, registry),
-                            profile)
-    if isinstance(node, ir.ForestRelational):
-        ci = ir.infer(node.child, registry, catalog)
-        return _forest_cost(registry.get(node.fn), ci.schema[node.x_col],
-                            ci.capacity, ir.resolve_phys(node, phys, registry),
-                            profile)
-    raise TypeError(type(node))
-
-
 def _calls(e: ir.Expr):
     if isinstance(e, ir.Call):
         yield e
@@ -334,8 +268,14 @@ def _calls(e: ir.Expr):
         yield from _calls(c)
 
 
+def _param_bytes(exprs, registry: Registry) -> float:
+    """Weight bytes of every ML call in ``exprs`` (streamed once per call)."""
+    return sum(registry.get(c.fn).param_bytes()
+               for e in exprs for c in _calls(e))
+
+
 # ---------------------------------------------------------------------------
-# physical-plan walk (costed lowering's candidate scorer)
+# the plan walks: time and memory
 # ---------------------------------------------------------------------------
 
 def _stage_info(stage, schema: Dict[str, int], capacity: int,
@@ -410,19 +350,9 @@ def _derive_info(node, registry: Registry, catalog: ir.Catalog,
     raise TypeError(type(node))
 
 
-def phys_node_info(node, registry: Registry, catalog: ir.Catalog
-                   ) -> Tuple[Dict[str, int], int]:
-    """(schema, capacity) of a physical node's output — the physical mirror
-    of ``ir.infer`` without row estimates (cost is capacity-driven)."""
-    return _derive_info(node, registry, catalog,
-                        tuple(phys_node_info(c, registry, catalog)
-                              for c in node.children()))
-
-
 def phys_op_costs(pplan, catalog: ir.Catalog,
                   profile: DeviceProfile) -> List[OpCost]:
-    """Per-operator OpCosts of a physical plan, through the same kernels as
-    the logical walk (tree-order lowering costs identically either way)."""
+    """Per-operator OpCosts of a physical plan."""
     from repro.core import physical as ph
     registry = pplan.registry
     out: List[OpCost] = []
@@ -441,14 +371,11 @@ def phys_op_costs(pplan, catalog: ir.Catalog,
                     out.append(_compact_cost(schema, cap, stage.capacity,
                                              profile))
                 elif isinstance(stage, ph.ProjectStage):
-                    fl = sum(ir.expr_flops(e, schema, registry)
-                             for _, e in stage.outputs)
-                    pb = 0.0
-                    for _, e in stage.outputs:
-                        for c in _calls(e):
-                            pb += registry.get(c.fn).param_bytes()
-                    out.append(_project_cost(fl, schema, nxt[0], pb, cap,
-                                             profile))
+                    exprs = _stage_exprs(stage)
+                    fl = sum(ir.expr_flops(e, schema, registry) for e in exprs)
+                    out.append(_project_cost(fl, schema, nxt[0],
+                                             _param_bytes(exprs, registry),
+                                             cap, profile))
                 schema, cap = nxt
             return schema, cap
         info = _derive_info(node, registry, catalog, child_infos)
@@ -500,10 +427,13 @@ def _dense_bytes(exprs, registry: Registry, cap,
 
 
 def _stage_exprs(stage) -> tuple:
-    """The expressions a Filter or Project (stage or node) evaluates."""
-    if hasattr(stage, "pred"):
+    """The expressions a Filter or Project stage evaluates."""
+    from repro.core import physical as ph
+    if isinstance(stage, ph.FilterStage):
         return (stage.pred,)
-    return tuple(e for _, e in getattr(stage, "outputs", ()))
+    if isinstance(stage, ph.ProjectStage):
+        return tuple(e for _, e in stage.outputs)
+    return ()
 
 
 def _view_bytes(schema, cap, view, profile) -> float:
@@ -518,8 +448,7 @@ def _view_bytes(schema, cap, view, profile) -> float:
 
 def phys_peak_memory(pplan, catalog: ir.Catalog,
                      profile: DeviceProfile) -> float:
-    """Peak working set of a physical plan (max across operators), the
-    physical mirror of ``node_mem``.
+    """Peak working set of a physical plan (max across operators).
 
     A cross join's output is a view of its sides (``ops.cross_join``
     broadcasts them; XLA fuses the broadcast into the row-local stages
@@ -564,9 +493,7 @@ def phys_peak_memory(pplan, catalog: ir.Catalog,
                         view = (view[0] & set(schema) - made, view[1])
                 m = _view_bytes(schema, cap, view, profile) + act
                 if isinstance(stage, ph.ProjectStage):
-                    for _, e in stage.outputs:
-                        for c in _calls(e):
-                            m += registry.get(c.fn).param_bytes()
+                    m += _param_bytes(_stage_exprs(stage), registry)
                 peak = max(peak, m)
             return schema, cap, view
         schema, cap = _derive_info(node, registry, catalog, child_infos)
@@ -592,90 +519,20 @@ def phys_peak_memory(pplan, catalog: ir.Catalog,
     return peak
 
 
-# ---------------------------------------------------------------------------
-# memory (peak working set) — the paper's OOM axis (Table I, Fig. 6)
-# ---------------------------------------------------------------------------
-
-def node_mem(node: ir.RelNode, registry: Registry, catalog: ir.Catalog,
-             profile: DeviceProfile, phys: PhysMap = None) -> float:
-    """Peak bytes over the plan (max across operators); a cross join's
-    output is a view of its sides, as in ``phys_peak_memory``."""
-    peak, view = _mem_walk(node, registry, catalog, profile, phys)
-    if view is not None:  # the result is materialized
-        peak = max(peak, _out_bytes(node, registry, catalog, profile))
-    return peak
-
-
-def _out_bytes(node, registry, catalog, profile) -> float:
-    out = ir.infer(node, registry, catalog)
-    return _row_bytes(out.schema, profile) * out.capacity
-
-
-def _mem_walk(node, registry, catalog, profile, phys):
-    """(peak bytes of the subtree, the view its output is: None, or the
-    cross join's columns it still holds unmaterialized and their sides'
-    bytes)."""
-    kids = [_mem_walk(c, registry, catalog, profile, phys)
-            for c in node.children()]
-    peak = max((k[0] for k in kids), default=0.0)
-    view = kids[0][1] if kids else None
-    if isinstance(node, (ir.Filter, ir.Project)):
-        ci = ir.infer(node.child, registry, catalog)
-        out = ir.infer(node, registry, catalog)
-        m = _dense_bytes(_stage_exprs(node), registry, ci.capacity, profile)
-        if isinstance(node, ir.Project):
-            for _, e in node.outputs:
-                for c in _calls(e):
-                    m += registry.get(c.fn).param_bytes()
-        if view is not None:
-            made = ({n for n, _ in node.outputs}
-                    if isinstance(node, ir.Project) else set())
-            view = (view[0] & set(out.schema) - made, view[1])
-        return max(peak, m + _view_bytes(out.schema, out.capacity, view,
-                                         profile)), view
-    for c, (_, v) in zip(node.children(), kids):
-        if v is not None and not isinstance(node, ir.Compact):
-            # consumed by a non-row-local operator (a Compact gathers it)
-            peak = max(peak, _out_bytes(c, registry, catalog, profile))
-    if isinstance(node, ir.CrossJoin):
-        side = sum(_out_bytes(c, registry, catalog, profile)
-                   for c in node.children())
-        out = ir.infer(node, registry, catalog)
-        return max(peak, side), (frozenset(out.schema), side)
-    return max(peak, _local_mem(node, registry, catalog, profile, phys)), None
-
-
-def _local_mem(node, registry, catalog, profile, phys=None):
-    if isinstance(node, ir.Scan):
-        st = catalog.stats[node.table]
-        return _row_bytes({c: s.dim for c, s in st.columns.items()}, profile) * st.capacity
-    out = ir.infer(node, registry, catalog)
-    base = _row_bytes(out.schema, profile) * out.capacity
-    if isinstance(node, ir.Project):
-        pb = 0.0
-        for _, e in node.outputs:
-            for c in _calls(e):
-                pb += registry.get(c.fn).param_bytes()
-        return base + pb
-    if isinstance(node, ir.BlockedMatmul):
-        fn = registry.get(node.fn)
-        # streamed: only one weight tile resident at a time
-        return base + fn.param_bytes() / max(ir.resolve_phys(node, phys, registry).n_tiles, 1)
-    if isinstance(node, ir.ForestRelational):
-        fn = registry.get(node.fn)
-        p = fn.graph.nodes[0].atom.params
-        n_trees = max(int(p["feat"].shape[0]), 1)  # per-tree streaming
-        return base + fn.param_bytes() / n_trees
-    return base
+def _physical(plan, catalog: ir.Catalog):
+    """``plan`` as the physical plan the walks cost: a logical plan's
+    tree-order lowering, a physical plan as it is."""
+    from repro.core import physical as ph
+    if isinstance(plan, ph.PhysicalPlan):
+        return plan
+    from repro.core.lowering import lower
+    return lower(plan, catalog, costed=False)
 
 
 def plan_peak_memory(plan, catalog: ir.Catalog,
                      profile: DeviceProfile | None = None) -> float:
-    from repro.core import physical as ph
-    profile = profile or default_profile()
-    if isinstance(plan, ph.PhysicalPlan):
-        return phys_peak_memory(plan, catalog, profile)
-    return node_mem(plan.root, plan.registry, catalog, profile, plan.phys)
+    return phys_peak_memory(_physical(plan, catalog), catalog,
+                            profile or default_profile())
 
 
 # ---------------------------------------------------------------------------
@@ -698,17 +555,14 @@ def plan_cost(plan, catalog: ir.Catalog,
     non-finite budget is explicitly unlimited (callers that already
     checked the peak themselves — costed lowering's hard gate — pass
     ``inf`` to skip the redundant peak walk)."""
-    from repro.core import physical as ph
     profile = profile or default_profile()
     if memory_budget is None:
         memory_budget = profile.memory_budget
-    if isinstance(plan, ph.PhysicalPlan):
-        t = sum(op_time(oc, profile)
-                for oc in phys_op_costs(plan, catalog, profile))
-    else:
-        t = node_cost(plan.root, plan.registry, catalog, profile, plan.phys)
+    pplan = _physical(plan, catalog)
+    t = sum(op_time(oc, profile)
+            for oc in phys_op_costs(pplan, catalog, profile))
     if memory_budget is not None and np.isfinite(memory_budget):
-        peak = plan_peak_memory(plan, catalog, profile)
+        peak = phys_peak_memory(pplan, catalog, profile)
         if peak > memory_budget:
             t *= OVER_BUDGET * peak / memory_budget
             tracing.count("cost.demoted")
@@ -742,14 +596,8 @@ class CostBreakdown:
 
 def plan_cost_breakdown(plan, catalog: ir.Catalog,
                         profile: DeviceProfile | None = None) -> CostBreakdown:
-    from repro.core import physical as ph
     profile = profile or default_profile()
-    if isinstance(plan, ph.PhysicalPlan):
-        ocs = phys_op_costs(plan, catalog, profile)
-    else:
-        ocs = [oc for oc in
-               (_node_op_cost(n, plan.registry, catalog, profile, plan.phys)
-                for n in ir.walk(plan.root)) if oc is not None]
+    ocs = phys_op_costs(_physical(plan, catalog), catalog, profile)
     return CostBreakdown(
         flops=sum(oc.flops for oc in ocs),
         hbm_bytes=sum(oc.data_bytes for oc in ocs if oc.bw == "hbm"),
@@ -771,14 +619,8 @@ def batched_plan_cost(plan, catalog: ir.Catalog, batch_size: int,
     overhead per shard. ``ways=1`` is the vmapped single-device realization;
     the serving tier's vmapped-vs-sharded choice compares the two
     (``costed_lowering.choose_batch_realization``)."""
-    from repro.core import physical as ph
     profile = profile or default_profile()
-    if isinstance(plan, ph.PhysicalPlan):
-        ocs = phys_op_costs(plan, catalog, profile)
-    else:
-        ocs = [oc for oc in
-               (_node_op_cost(n, plan.registry, catalog, profile, plan.phys)
-                for n in ir.walk(plan.root)) if oc is not None]
+    ocs = phys_op_costs(_physical(plan, catalog), catalog, profile)
     scale = batch_size / max(ways, 1)
     t = sum(op_time(oc, profile, data_scale=scale) for oc in ocs)
     if ways > 1:

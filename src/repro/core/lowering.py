@@ -31,22 +31,23 @@ from repro.core import physical as ph
 # plan-level realizations and the node-level backend they resolve to: the
 # sharded path splits the stacked batch axis *around* the plan body, so each
 # device's slice runs the ordinary pure-XLA program
-_PLAN_LEVEL_BACKENDS = {"sharded": "jnp"}
+PLAN_LEVEL_BACKENDS = {"sharded": "jnp"}
 
 
 def _config(plan: ir.Plan, node: ir.RelNode,
             backend: Optional[str]) -> ir.PhysConfig:
     cfg = plan.phys_for(node)  # resolves the weight-derived n_tiles default
     if backend is not None:
-        backend = _PLAN_LEVEL_BACKENDS.get(backend, backend)
+        backend = PLAN_LEVEL_BACKENDS.get(backend, backend)
         cfg = ir.PhysConfig(mode=cfg.mode, backend=backend, n_tiles=cfg.n_tiles)
     return cfg
 
 
-_ROW_LOCAL = (ir.Filter, ir.Project, ir.Compact)
+ROW_LOCAL = (ir.Filter, ir.Project, ir.Compact)
 
 
-def _as_stage(node: ir.RelNode) -> ph.Stage:
+def as_stage(node: ir.RelNode) -> ph.Stage:
+    """The pipeline stage a row-local logical node becomes."""
     if isinstance(node, ir.Filter):
         return ph.FilterStage(pred=node.pred)
     if isinstance(node, ir.Project):
@@ -58,13 +59,13 @@ def _as_stage(node: ir.RelNode) -> ph.Stage:
 
 def _lower_node(node: ir.RelNode, plan: ir.Plan, catalog: ir.Catalog,
                 backend: Optional[str]) -> ph.PhysNode:
-    if isinstance(node, _ROW_LOCAL):
+    if isinstance(node, ROW_LOCAL):
         # collect the maximal Filter/Project/Compact chain (Velox-style
         # pipeline); stages execute source-to-sink, so reverse the walk
         stages: list = []
         cur = node
-        while isinstance(cur, _ROW_LOCAL):
-            stages.append(_as_stage(cur))
+        while isinstance(cur, ROW_LOCAL):
+            stages.append(as_stage(cur))
             cur = cur.children()[0]
         return ph.PPipeline(child=_lower_node(cur, plan, catalog, backend),
                             stages=tuple(reversed(stages)))

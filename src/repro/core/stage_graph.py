@@ -47,14 +47,8 @@ import numpy as np
 
 from repro.core import evaluator, ir
 from repro.core import physical as ph
+from repro.core.lowering import PLAN_LEVEL_BACKENDS, ROW_LOCAL, as_stage
 from repro.mlfuncs.registry import Registry
-
-# plan-level realizations resolve per-node to the pure-XLA path (the sharded
-# path splits the stacked batch axis *around* the plan body) — kept in sync
-# with repro.core.lowering._PLAN_LEVEL_BACKENDS
-PLAN_LEVEL_BACKENDS = {"sharded": "jnp"}
-
-_ROW_LOCAL = (ir.Filter, ir.Project, ir.Compact)
 
 # enumeration bound: per-pipeline topological orders
 ORDER_CAP = 8
@@ -99,23 +93,18 @@ class StageVertex:
 
 
 def _vertex(node: ir.RelNode) -> StageVertex:
+    stage = as_stage(node)
     if isinstance(node, ir.Filter):
-        return StageVertex(ph.FilterStage(pred=node.pred),
-                           reads=frozenset(node.pred.cols()),
+        return StageVertex(stage, reads=frozenset(node.pred.cols()),
                            writes=frozenset(), is_filter=True)
     if isinstance(node, ir.Project):
         reads = frozenset().union(*(e.cols() for _, e in node.outputs)) \
             if node.outputs else frozenset()
-        return StageVertex(ph.ProjectStage(outputs=node.outputs,
-                                           keep=node.keep),
-                           reads=reads,
+        return StageVertex(stage, reads=reads,
                            writes=frozenset(n for n, _ in node.outputs),
                            barrier=node.keep is not None)
-    if isinstance(node, ir.Compact):
-        return StageVertex(ph.CompactStage(capacity=node.capacity),
-                           reads=frozenset(), writes=frozenset(),
-                           is_compact=True)
-    raise TypeError(type(node))
+    return StageVertex(stage, reads=frozenset(), writes=frozenset(),
+                       is_compact=True)
 
 
 def _edges(vertices: Tuple[StageVertex, ...]) -> frozenset:
@@ -637,7 +626,7 @@ class _Builder:
         # maximal Filter/Project/Compact chain; stages run source-to-sink
         chain: List[ir.RelNode] = []
         cur = node
-        while isinstance(cur, _ROW_LOCAL):
+        while isinstance(cur, ROW_LOCAL):
             chain.append(cur)
             cur = cur.children()[0]
         chain.reverse()  # source-to-sink
@@ -677,7 +666,7 @@ class _Builder:
                          part_sid=self._part_site())
 
     def visit(self, node: ir.RelNode) -> GNode:
-        if isinstance(node, _ROW_LOCAL):
+        if isinstance(node, ROW_LOCAL):
             return self._pipeline(node)
         if isinstance(node, ir.Scan):
             return GScan(table=node.table)

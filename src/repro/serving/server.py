@@ -95,13 +95,15 @@ class QueryServer:
         self.mesh = mesh
         from repro.core import mesh as mesh_util
         self._ways = mesh_util.batch_ways(mesh) if mesh is not None else 1
-        # per-device working-set budget: installed on the cache's profile,
-        # so every costed-lowering decision this server triggers sees it.
-        # A submitted plan that busts it is routed to the *partitioned*
-        # executable (operators sharded over the mesh) instead of being
-        # served on one device (thrashing) or refused.
+        # per-device working-set budget: installed on the cache's profile
+        # through recalibrate (decisions memoized under the old budget are
+        # re-derived), so every costed-lowering decision this server
+        # triggers sees it. A submitted plan that busts it is routed to the
+        # *partitioned* executable (operators sharded over the mesh)
+        # instead of being served on one device (thrashing) or refused.
         if memory_budget is not None:
-            self.cache.profile.memory_budget = float(memory_budget)
+            self.cache.recalibrate(dataclasses.replace(
+                self.cache.profile, memory_budget=float(memory_budget)))
         self.clock = clock
         self.signatures: Dict[str, SignatureStats] = {}
         self.completed = 0

@@ -3,15 +3,18 @@ strictly cheaper plans where the oracle finds them, one shared plan_cost
 entry point across MCTS and lower(), and calibration-driven re-lowering
 without stale-executable aliasing in the PlanCache."""
 import dataclasses
+import functools
+import hashlib
 
 import numpy as np
 import pytest
 
-from repro.core import cost, costed_lowering, executor, ir, stage_graph
+from repro.core import cost, costed_lowering, executor, ir, planner, stage_graph
 from repro.core import physical as ph
 from repro.core.lowering import lower
 from repro.core.mcts import VanillaMCTS
 from repro.core.plan_cache import PlanCache
+from repro.core.rules import base as rules_base
 from repro.data import workloads
 from repro.serving import feedback
 
@@ -122,16 +125,113 @@ def test_mcts_and_lowering_share_the_plan_cost_oracle(monkeypatch):
     assert calls["n"] > lowering_calls
 
 
-def test_plan_cost_accepts_both_plan_levels():
-    """Logical and (tree-order) physical costing agree bit-for-bit: one set
-    of per-operator kernels behind one entry point."""
-    profile = cost.DeviceProfile.detect()
-    for name in sorted(workloads.ALL_WORKLOADS):
-        w = workloads.ALL_WORKLOADS[name](scale=0.3)
-        c_log = cost.plan_cost(w.plan, w.catalog, profile)
-        c_phys = cost.plan_cost(lower(w.plan, w.catalog, costed=False),
-                                w.catalog, profile)
-        assert c_phys == pytest.approx(c_log, rel=1e-12), name
+# Each workload's logical plan at scale 0.3 under ``cost.TPU_PROFILE``:
+# (plan_cost, plan_peak_memory, batched_plan_cost at B=8 with ways 1 and 4,
+# plan_cost_breakdown's fields, sha256[:16] of the signature of the plan
+# optimize_vanilla_mcts(iterations=20, seed=0) chooses, with the rules'
+# fresh-column counter at zero). Recorded when the oracle had a separate
+# walk over logical plans; the tree-order walk must reproduce them.
+_PINNED = {
+    "analytics_q1": (
+        7.164180708180708e-06, 620536.0, 1.006900122100122e-05,
+        1.1579155067155065e-05,
+        (3122934.0, 339864, 613600.0, 0, 3, 7.164180708180708e-06, 0.0),
+        "0bb2a4466e9637a5"),
+    "analytics_q2": (
+        1.1796454212454212e-05, 250272, 2.4319189255189254e-05,
+        1.758541636141636e-05,
+        (26350.0, 1465160, 6136.0, 0, 5, 1.1796454212454212e-05, 0.0),
+        "354217ae85b4b586"),
+    "analytics_q3": (
+        1.5226422466422464e-05, 615280.0, 3.256693528693529e-05,
+        2.1703638583638583e-05,
+        (779460.0, 2028840, 613600.0, 0, 6, 1.5226422466422466e-05, 0.0),
+        "230586dfed7d67cf"),
+    "rec_q1": (
+        1.3753811965811966e-05, 1756032.0, 2.5264456992122374e-05,
+        1.931611424803059e-05,
+        (164720990.0, 1010548.0, 425824.0, 0, 6, 1.3753811965811965e-05, 0.0),
+        "2c20200b29f5a11c"),
+    "rec_q2": (
+        0.00013131704813224002, 55726976.0, 0.0009649106414681765,
+        0.00025440184718023093,
+        (13786884936.0, 54590496.0, 35851012.0, 0, 6, 0.00013131704813224,
+         0.0),
+        "b28db48025407925"),
+    "rec_q3": (
+        0.00010607038827838827, 36275296.0, 0.00015047818315018315,
+        0.00011641435897435897,
+        (1008331464.0, 5195712.0, 71847936.0, 0, 6, 0.00010607038827838827,
+         0.0),
+        "76cc3fbb6a73bf20"),
+    "retail_q1": (
+        8.401470085470085e-06, 128324.0, 1.1050358974358974e-05,
+        1.2779882783882782e-05,
+        (2689696.0, 309920, 18884.0, 0, 4, 8.401470085470085e-06, 0.0),
+        "b4c5127994c1257e"),
+    "retail_q2": (
+        1.4552727716727717e-05, 173996.0, 1.729631746031746e-05,
+        1.8944669108669106e-05,
+        (2430960.0, 321000, 131684.0, 0, 7, 1.4552727716727717e-05, 0.0),
+        "990562b568a5bbc4"),
+    "retail_q3": (
+        1.1219907203907204e-05, 1052336.0, 1.9155907203907203e-05,
+        1.635362148962149e-05,
+        (50975400.0, 928512.0, 70592.0, 0, 5, 1.1219907203907204e-05, 0.0),
+        "72141c2eb9290c98"),
+    "simple_q1": (
+        2.0385641025641026e-06, 27264.0, 2.1247179487179486e-06,
+        6.050871794871795e-06,
+        (92160.0, 10080, 21504.0, 0, 1, 2.0385641025641026e-06, 0.0),
+        "4a163320b2200f95"),
+    "simple_q2": (
+        4.263052503052503e-06, 66240, 5.779633699633699e-06,
+        8.47970695970696e-06,
+        (295936.0, 177440, 38000.0, 0, 2, 4.263052503052503e-06, 0.0),
+        "3f4afcf53cc7fefa"),
+    "simple_q3": (
+        4.071868131868132e-06, 19440, 4.574432234432234e-06,
+        8.143663003663004e-06,
+        (22440.0, 58800, 60.0, 0, 2, 4.071868131868132e-06, 0.0),
+        "8c1e9bf2816bc262"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _pinned_workload(name):
+    return workloads.ALL_WORKLOADS[name](scale=0.3)
+
+
+@pytest.mark.parametrize("check", ["costs", "mcts_plan"])
+@pytest.mark.parametrize("name", sorted(_PINNED))
+def test_oracle_numbers_are_pinned(name, check, monkeypatch):
+    """A logical plan costs what it always has: the oracle's time, memory,
+    breakdown and batched numbers, and the plan MCTS chooses by them."""
+    w = _pinned_workload(name)
+    prof = cost.TPU_PROFILE
+    c, peak, b8, b8w4, fields, sig = _PINNED[name]
+    if check == "mcts_plan":
+        # rewrites name new columns from a process-wide counter, and the
+        # names are part of the signature
+        monkeypatch.setattr(rules_base, "_fresh_counter", [0])
+        best, _ = planner.optimize_vanilla_mcts(
+            w.plan, w.catalog, iterations=20, seed=0,
+            cost_fn=planner.analytic_cost_fn(w.catalog, prof))
+        got = best.signature()
+        assert hashlib.sha256(got.encode()).hexdigest()[:16] == sig, got
+        return
+    assert cost.plan_cost(w.plan, w.catalog, prof) == pytest.approx(
+        c, rel=1e-12)
+    assert cost.plan_peak_memory(w.plan, w.catalog, prof) == pytest.approx(
+        peak, rel=1e-12)
+    assert cost.batched_plan_cost(w.plan, w.catalog, 8, prof) \
+        == pytest.approx(b8, rel=1e-12)
+    assert cost.batched_plan_cost(w.plan, w.catalog, 8, prof, ways=4) \
+        == pytest.approx(b8w4, rel=1e-12)
+    b = cost.plan_cost_breakdown(w.plan, w.catalog, prof)
+    got = (b.flops, b.hbm_bytes, b.param_bytes, b.vmem_bytes, b.n_ops,
+           b.seconds, b.n_coll)
+    assert got == pytest.approx(fields, rel=1e-12)
 
 
 # ---------------------------------------------------------------------------
