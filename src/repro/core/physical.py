@@ -40,8 +40,10 @@ from typing import Dict, Mapping, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core import ir
 from repro.core.evaluator import as_column, eval_expr
+from repro.mlfuncs.functions import MERGES
 from repro.mlfuncs.registry import Registry
 from repro.relational import ops
 from repro.relational.table import Table
@@ -466,17 +468,115 @@ def _run_stage(stage: Stage, t: Table, registry: Registry) -> Table:
     raise TypeError(type(stage))
 
 
+def _grid_form(e: ir.Expr, owner: Mapping[str, int], registry: Registry):
+    """How a Project output over a cross join is scored from the join's two
+    sides without its pairs: ``(merge atom, ((ref, args), (ref, args)))``,
+    per side (left, then right) the graph value the merge reads and the
+    indices of the call arguments that side computes it from; or None
+    where ``e`` is not a ``Call`` whose graph ends in a ``MERGES`` atom over
+    one argument from each side. ``owner`` maps the join's output columns
+    to their side (0 left, 1 right)."""
+    if not isinstance(e, ir.Call):
+        return None
+    graph = registry.get(e.fn).graph
+    if graph is None:
+        return None
+    merge = graph.node(graph.out)
+    if merge.atom.kind not in MERGES or len(merge.args) != 2:
+        return None
+    arg_side = []
+    for a in e.args:
+        sides = {owner.get(c) for c in a.cols()}
+        if len(sides) != 1 or None in sides:
+            return None
+        arg_side.append(sides.pop())
+    deps = graph.input_deps()
+    per_side = {}
+    for ref in merge.args:
+        ins = frozenset([ref[1]]) if ref[0] == "in" else deps[ref[1]]
+        sides = {arg_side[k] for k in ins}
+        if len(sides) != 1:
+            return None
+        per_side[sides.pop()] = (ref, tuple(sorted(ins)))
+    if len(per_side) != 2:
+        return None
+    return merge.atom, (per_side[0], per_side[1])
+
+
+def score_cross_join(node: PCrossJoin, stage: ProjectStage, lt: Table,
+                     rt: Table, registry: Registry) -> Optional[Table]:
+    """``stage`` run on ``node``'s output, given the join's sides, without
+    the pairs' vector columns, or None where ``_grid_form`` finds a stage
+    output it cannot score this way.
+
+    Each side runs its part of every call once per row of its own; the
+    merge is one [Na, Nb] product (``Atom.apply_grid``), read in the cross
+    join's row order (``ia * Nb + ib``). Only the columns the stage keeps,
+    and the valid mask, are repeated over the pairs."""
+    owner = {node.aprefix + n: 0 for n in lt.columns}
+    owner.update({node.bprefix + n: 1 for n in rt.columns})  # right shadows
+    forms = [_grid_form(e, owner, registry) for _, e in stage.outputs]
+    if not forms or None in forms:
+        return None
+    sides = (lt.rename({n: node.aprefix + n for n in lt.columns}),
+             rt.rename({n: node.bprefix + n for n in rt.columns}))
+    with jax.named_scope("project"):
+        scores = {}
+        for (name, e), (merge, per_side) in zip(stage.outputs, forms):
+            graph = registry.get(e.fn).graph
+            a, b = (graph.apply_ref(ref, {k: jnp.asarray(
+                        eval_expr(e.args[k], t, registry)) for k in args})
+                    for (ref, args), t in zip(per_side, sides))
+            scores[name] = merge.apply_grid(a, b).reshape(-1)
+    with jax.named_scope("cross_join"):
+        keep = stage.keep
+
+        def kept(t: Table, prefix: str, side: int) -> Table:
+            if keep is None:
+                return t
+            return t.select([n for n in t.columns
+                             if prefix + n in keep and owner[prefix + n] == side])
+
+        pairs = ops.cross_join(kept(lt, node.aprefix, 0),
+                               kept(rt, node.bprefix, 1),
+                               node.aprefix, node.bprefix)
+    tracing.count("cross_join.scored")
+    return ops.project(pairs, scores, keep=keep)
+
+
+def _materialize_cross_join(node: PCrossJoin, lt: Table, rt: Table) -> Table:
+    tracing.count("cross_join.materialized")
+    with jax.named_scope("cross_join"):
+        return ops.cross_join(lt, rt, node.aprefix, node.bprefix)
+
+
 def run_node(node: PhysNode, tables: Dict[str, Table],
              registry: Registry, axis: Optional[str] = None) -> Table:
     """Run ``node``'s subtree. Each operator's own work runs inside
     ``jax.named_scope(<operator>)`` (``tracing.OPERATORS``) and its children
     outside it, so that a compiled op's innermost scope names the operator
-    that did the work."""
+    that did the work.
+
+    A pipeline whose first stage projects over a cross join scores it from
+    the join's sides where it can (``score_cross_join``), and otherwise
+    materializes the pairs."""
     if isinstance(node, PScan):
         return tables[node.table]
     if isinstance(node, PPipeline):
-        t = run_node(node.child, tables, registry, axis)
-        for stage in node.stages:
+        stages = node.stages
+        if isinstance(node.child, PCrossJoin) and stages and isinstance(
+                stages[0], ProjectStage):
+            x = node.child
+            lt = run_node(x.left, tables, registry, axis)
+            rt = run_node(x.right, tables, registry, axis)
+            t = score_cross_join(x, stages[0], lt, rt, registry)
+            if t is None:
+                t = _materialize_cross_join(x, lt, rt)
+            else:
+                stages = stages[1:]
+        else:
+            t = run_node(node.child, tables, registry, axis)
+        for stage in stages:
             t = _run_stage(stage, t, registry)
         return t
     if isinstance(node, PJoin):
@@ -488,8 +588,7 @@ def run_node(node: PhysNode, tables: Dict[str, Table],
     if isinstance(node, PCrossJoin):
         lt = run_node(node.left, tables, registry, axis)
         rt = run_node(node.right, tables, registry, axis)
-        with jax.named_scope("cross_join"):
-            return ops.cross_join(lt, rt, node.aprefix, node.bprefix)
+        return _materialize_cross_join(node, lt, rt)
     if isinstance(node, PAggregate):
         t = run_node(node.child, tables, registry, axis)
         with jax.named_scope("aggregate"):

@@ -10,7 +10,7 @@ inputs are vector/scalar columns of the enclosing relation.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +172,23 @@ class Atom:
             return jnp.broadcast_to(v, (xs[0].shape[0],) + v.shape)
         raise ValueError(f"unknown atom kind {k}")
 
+    def apply_grid(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """A merge atom (``MERGES``) of every row of ``a`` [Na, d] with every
+        row of ``b`` [Nb, d]: the [Na, Nb] grid of what ``apply`` gives the
+        row pairs, as one product at float32 accuracy."""
+        num = jnp.dot(a, b.T, precision=jax.lax.Precision.HIGHEST)
+        if self.kind == "dot":
+            return num
+        if self.kind == "cossim":
+            den = jnp.outer(jnp.linalg.norm(a, axis=-1),
+                            jnp.linalg.norm(b, axis=-1)) + 1e-8
+            return num / den
+        raise ValueError(f"{self.kind} is not a merge atom")
+
+
+# atoms that merge two vectors into one number per row, and have a grid form
+MERGES = ("cossim", "dot")
+
 
 def _forest_apply(p: Dict, x: jax.Array, backend: str) -> jax.Array:
     """Array-form decision forest: complete binary trees of fixed depth.
@@ -225,11 +242,25 @@ class MLGraph:
         raise KeyError(nid)
 
     def apply(self, *inputs: jax.Array) -> jax.Array:
+        return self.apply_ref(("node", self.out), dict(enumerate(inputs)))
+
+    def apply_ref(self, ref: Ref, inputs: Mapping[int, jax.Array]) -> jax.Array:
+        """The value at ``ref``, computed from the graph inputs it depends on
+        (``inputs`` by index; the others may be absent), running only the
+        nodes it needs."""
+        if ref[0] == "in":
+            return inputs[ref[1]]
+        need = {ref[1]}
+        for n in reversed(self.nodes):
+            if n.id in need:
+                need |= {r[1] for r in n.args if r[0] == "node"}
         vals: Dict[int, jax.Array] = {}
         for n in self.nodes:
-            xs = [inputs[r[1]] if r[0] == "in" else vals[r[1]] for r in n.args]
-            vals[n.id] = n.atom.apply(*xs)
-        return vals[self.out]
+            if n.id in need:
+                xs = [inputs[r[1]] if r[0] == "in" else vals[r[1]]
+                      for r in n.args]
+                vals[n.id] = n.atom.apply(*xs)
+        return vals[ref[1]]
 
     def infer_dims(self, in_dims: Sequence[int]) -> Dict[int, int]:
         dims: Dict[int, int] = {}

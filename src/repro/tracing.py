@@ -34,7 +34,10 @@ published_s: Dict[str, float] = defaultdict(float)
 # events counted over the process's life (``count``): "lowering.pruned",
 # lowering candidates the memory budget rejected; "cost.demoted", plans the
 # over-budget penalty of ``cost.plan_cost`` priced up; "fallback.<what>",
-# requests a plan cache served otherwise than asked (``PlanCache.fallbacks``)
+# requests a plan cache served otherwise than asked (``PlanCache.fallbacks``);
+# "cross_join.scored", traces of a Project scored from a cross join's sides
+# (``physical.score_cross_join``), and "cross_join.materialized", traces of
+# a cross join whose pairs were built
 counts: Dict[str, int] = defaultdict(int)
 
 

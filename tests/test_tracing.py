@@ -189,3 +189,18 @@ def test_budget_counters_move_when_a_budget_is_below_a_plan_peak():
     stats = QueryServer().stats()["budget"]
     assert stats == {"lowering.pruned": tracing.counts["lowering.pruned"],
                      "cost.demoted": tracing.counts["cost.demoted"]}
+
+
+@pytest.mark.parametrize("name,moved,still", [
+    ("rec_q1", "cross_join.scored", "cross_join.materialized"),
+    ("rec_q2", "cross_join.materialized", "cross_join.scored"),
+])
+def test_a_trace_counts_whether_its_cross_join_was_scored(name, moved, still):
+    """rec_q1's two-tower cosine is scored from the cross join's sides;
+    rec_q2's interest DNN filters the pairs first, so they are built."""
+    w = workloads.ALL_WORKLOADS[name](scale=SCALE)
+    before = {k: tracing.counts[k] for k in (moved, still)}
+    jax.block_until_ready(PlanCache().get_or_compile(w.plan, w.catalog)(
+        dict(w.catalog.tables)))
+    assert tracing.counts[moved] > before[moved]
+    assert tracing.counts[still] == before[still]
